@@ -239,8 +239,11 @@ def _admission_difficulty(bench, smoke=False):
 
 def _probe_devices(n_devices, horizon, reps, rate_scale, window):
     """Spawn one ``benchmarks.scaling_probe`` subprocess with the forced
-    host-device flag set BEFORE the child's first jax import."""
+    host-device flag set BEFORE the child's first jax import. The child
+    runs on the CPU backend: the parent already holds any accelerator,
+    and forced host devices exist only there."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={max(n_devices, 1)}"
     cmd = [sys.executable, "-m", "benchmarks.scaling_probe",
@@ -271,6 +274,7 @@ def _scaling(bench, smoke):
              f"tasks_per_sec={r['tasks_per_sec']:.0f};"
              f"arrived={r['arrived']};done_all={r['done_all']};"
              f"stolen={r['stolen']};devices={r['devices']};"
+             f"platform={r['platform']};"
              f"digest={r['digest'][:12]}")
     speedup = res[2]["tasks_per_sec"] / max(res[1]["tasks_per_sec"], 1e-9)
     emit("labelstream_scaling_parity", 0.0,
@@ -295,7 +299,8 @@ def _scaling(bench, smoke):
     for d, r in big.items():
         emit(f"labelstream_scaling_large_d{d}", r["wall_s"] * 1e6,
              f"tasks_per_sec={r['tasks_per_sec']:.0f};"
-             f"arrived={r['arrived']};digest={r['digest'][:12]}")
+             f"arrived={r['arrived']};platform={r['platform']};"
+             f"digest={r['digest'][:12]}")
         bench[f"scaling_large_tasks_per_sec_d{d}"] = r["tasks_per_sec"]
     bench["scaling_large_tasks"] = float(big[1]["arrived"])
     bench["scaling_large_parity_ok"] = float(

@@ -12,6 +12,8 @@ import time
 
 def main() -> None:
     smoke = "--smoke" in sys.argv
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     # bench_simfast forces one XLA host device per core; import it before
     # anything initializes jax so the flag takes effect
     from benchmarks import bench_simfast

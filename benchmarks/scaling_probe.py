@@ -57,6 +57,7 @@ def probe(n_devices: int, horizon: int, reps: int, rate_scale: float,
                  + int(np.asarray(out["in_flight_end"]).sum()))
     return {
         "devices": int(jax.device_count()),
+        "platform": jax.devices()[0].platform,
         "n_devices": n_devices,
         "digest": h.hexdigest(),
         "arrived": arrived,

@@ -46,9 +46,11 @@ def _ds_estep_kernel(idx_ref, rows_ref, logp_ref, post_ref, *, n_votes,
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (block_t, rows.shape[0]), 1)
     for v in range(n_votes):
         # one-hot MXU gather of each task's v-th vote row; padded votes hit
-        # the all-zero null row so no mask is needed
+        # the all-zero null row so no mask is needed. fp32 contraction:
+        # the gather must return the rows exactly, not rounded to bf16
         oh = (idx[:, v][:, None] == row_ids).astype(jnp.float32)
-        acc = acc + jnp.dot(oh, rows, preferred_element_type=jnp.float32)
+        acc = acc + jnp.dot(oh, rows, preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     logp_ref[...] = acc
     m = acc.max(axis=1, keepdims=True)
     p = jnp.exp(acc - m)

@@ -93,6 +93,16 @@ def _row_table(log_conf, n_workers, n_classes):
     return jnp.concatenate([rows, jnp.zeros((1, n_classes), rows.dtype)])
 
 
+def estep_mode(use_kernel: Optional[bool] = None) -> "tuple[bool, bool]":
+    """``(use_kernel, interpret)`` for the E-step on the current backend.
+
+    ``use_kernel=None`` selects the fused Pallas E-step on TPU and the
+    pure-jnp path elsewhere; an explicit ``use_kernel=True`` runs the
+    kernel everywhere (interpret mode off-TPU)."""
+    on_tpu = jax.default_backend() == "tpu"
+    return (on_tpu if use_kernel is None else bool(use_kernel)), not on_tpu
+
+
 def _estep(log_conf, idx, n_workers, n_classes, use_kernel, interpret):
     rows = _row_table(log_conf, n_workers, n_classes)
     if use_kernel:
@@ -183,14 +193,12 @@ def dawid_skene(labels, workers, mask, *, n_workers: int, n_classes: int,
     pure-jnp path elsewhere (the kernel still runs everywhere via
     ``use_kernel=True`` — interpret mode off-TPU).
     """
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel is None:
-        use_kernel = on_tpu
+    use_kernel, interpret = estep_mode(use_kernel)
     return _ds_jit(jnp.asarray(labels, jnp.int32),
                    jnp.asarray(workers, jnp.int32),
                    jnp.asarray(mask, bool),
                    int(n_workers), int(n_classes), int(iters),
-                   bool(one_coin), bool(use_kernel), not on_tpu)
+                   bool(one_coin), use_kernel, interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
@@ -211,14 +219,12 @@ def dawid_skene_batch(labels, workers, mask, *, n_workers: int,
     (scan over iterations) in lock-step. Jitted through a module-level
     cache, so repeated same-shaped calls do not retrace.
     """
-    on_tpu = jax.default_backend() == "tpu"
-    if use_kernel is None:
-        use_kernel = on_tpu
+    use_kernel, interpret = estep_mode(use_kernel)
     return _ds_batch_jit(jnp.asarray(labels, jnp.int32),
                          jnp.asarray(workers, jnp.int32),
                          jnp.asarray(mask, bool),
                          int(n_workers), int(n_classes), int(iters),
-                         bool(one_coin), bool(use_kernel), not on_tpu)
+                         bool(one_coin), use_kernel, interpret)
 
 
 def aggregate_votes(task_votes, n_classes: int, *, iters: int = 20,

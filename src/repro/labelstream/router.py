@@ -628,13 +628,16 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
     # (stale accuracy estimates at vote time are never revisited); the
     # offline EM re-explains every stored vote under the final confusions
     if cfg.refresh_every > 0:
-        from repro.labelstream.aggregate import _ds_em
+        from repro.labelstream.aggregate import _ds_em, estep_mode
+
+        use_kernel, interpret = estep_mode()
 
         def _refresh(_):
             vmask_r = (jnp.arange(cap)[None, :] < win["n_votes"][:, None]) \
                 & win["active"][:, None]
             em = _ds_em(win["vote_lab"][:Ws], win["vote_wid"][:Ws], vmask_r,
-                        P + 1, C, cfg.refresh_iters, False, False, True)
+                        P + 1, C, cfg.refresh_iters, False, use_kernel,
+                        interpret)
             lp = jnp.where((win["active"] & (win["n_votes"] > 0))[:, None],
                            em["log_posterior"], win["logpost"])
             vpw = em["votes_per_worker"][:P]
@@ -1303,7 +1306,6 @@ def _run_sharded_jit(cfg: StreamConfig, horizon: int):
     between ticks, nothing round-trips to host — and the keys buffer is
     donated. Reduced metrics come out replicated; the ``per_shard``
     diagnostics stay physically sharded over the "shard" axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
 
     from repro.distributed.sharding import leading_axis_specs
@@ -1334,8 +1336,9 @@ def _run_sharded_jit(cfg: StreamConfig, horizon: int):
         k: (leading_axis_specs(v, "shard", axis=1) if k == "per_shard"
             else jax.tree_util.tree_map(lambda _: Pspec(), v))
         for k, v in shapes.items()}
-    fn = shard_map(body, mesh=mesh, in_specs=(Pspec(), Pspec(), Pspec()),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(Pspec(), Pspec(), Pspec()),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -1859,7 +1862,6 @@ def _serve_tick_sharded_jit(cfg: StreamConfig):
     per-shard state subtrees live sharded over the "shard" axis, the
     gathered ``srv_*`` outputs come out replicated, and the state buffers
     are donated tick over tick)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
 
     from repro.launch.mesh import check_stream_sharding, make_stream_mesh
@@ -1894,10 +1896,10 @@ def _serve_tick_sharded_jit(cfg: StreamConfig):
                                            labels_in=lab_sh, bank=bank),
         state_shapes, arr_sh, arr_sh)
     rep_specs = jax.tree_util.tree_map(lambda _: Pspec(), out_shapes[1])
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(state_specs, Pspec("shard"), Pspec("shard"),
-                             Pspec("shard"), Pspec("shard")),
-                   out_specs=(state_specs, rep_specs), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(state_specs, Pspec("shard"), Pspec("shard"),
+                                 Pspec("shard"), Pspec("shard")),
+                       out_specs=(state_specs, rep_specs), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 

@@ -148,8 +148,6 @@ def run_cell(arch, shape_name, mesh_kind, *, outdir=None, attn_impl="auto",
         terms = hlo.roofline_terms(analysis)
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-            ca = ca[0] if ca else {}
         n_chips = mesh.devices.size
         n_params = count_params(model_template(cfg))
         tokens = (shape.global_batch * shape.seq_len

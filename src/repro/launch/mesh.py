@@ -7,9 +7,7 @@ import jax
 
 
 def _mesh_kwargs(n):
-    # jax.sharding.AxisType landed after 0.4.37; older jax defaults to Auto
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

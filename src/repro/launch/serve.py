@@ -29,53 +29,73 @@ async def _serve_forever(args):
           f"http://{srv.host}:{srv.port}  (POST /tasks, GET /labels/<id>, "
           "GET /stats, POST /shutdown)", flush=True)
     try:
-        while not srv._closed:
-            await asyncio.sleep(0.2)
+        await srv.wait_closed()
     finally:
         await srv.close()
 
 
-async def _smoke(args):
-    from repro.scenarios import get_scenario
+async def drive(spec, *, seed: int = 0, host: str | None = None,
+                n_clients: int = 4, per_client: int = 8,
+                submission=None, timeout_s: float = 60.0) -> dict:
+    """Serve ``spec`` on an ephemeral port and drive it over loopback HTTP.
+
+    ``n_clients`` concurrent keep-alive clients each submit ``per_client``
+    waited tasks; ``submission(i, k)``, if given, returns the extra
+    ``ServeClient.submit`` fields (``text``/``label``) of client ``i``'s
+    ``k``-th task. The server is shut down over HTTP afterwards. Returns
+    the answered count, the server's ``/stats`` taken before shutdown,
+    and ``ok``: every task answered with conservation intact."""
     from repro.serving.server import LabelServer, ServeClient
 
-    spec = get_scenario(args.scenario)
-    srv = LabelServer(spec, seed=args.seed, host=args.host, port=0,
+    srv = LabelServer(spec, seed=seed, host=host, port=0,
                       tick_interval_s=0.0)
     await srv.start()
-    print(f"smoke: serving {args.scenario!r} on port {srv.port}", flush=True)
-
-    n_clients, per_client = 4, 8
 
     async def client(i):
         c = await ServeClient(srv.host, srv.port).connect()
         out = []
-        for _ in range(per_client):
-            status, r = await c.submit(wait=True, timeout_s=60.0)
-            out.append((status, r))
+        for k in range(per_client):
+            extra = submission(i, k) if submission else {}
+            out.append(await c.submit(wait=True, timeout_s=timeout_s,
+                                      **extra))
         await c.aclose()
         return out
 
-    results = await asyncio.gather(*[client(i) for i in range(n_clients)])
-    answered = [r for out in results for (status, r) in out
-                if status == 200 and r["status"] == "done"]
-    stats = srv.stats()
-    c = await ServeClient(srv.host, srv.port).connect()
-    await c.shutdown()
-    await c.aclose()
-    await srv.close()
+    try:
+        results = await asyncio.gather(*[client(i)
+                                         for i in range(n_clients)])
+        if srv.error is None:
+            c = await ServeClient(srv.host, srv.port).connect()
+            stats = await c.stats()
+            await c.shutdown()
+            await c.aclose()
+        await srv.wait_closed()       # raises if the tick loop died
+    finally:
+        await srv.close()
     n = n_clients * per_client
-    ok = (len(answered) == n and stats["conservation"]
-          and stats["answered"] == n)
+    answered = sum(1 for out in results for status, r in out
+                   if status == 200 and r["status"] == "done")
+    return dict(submitted=n, answered=answered, stats=stats,
+                ok=(answered == n and stats["conservation"]
+                    and stats["answered"] == n))
+
+
+async def _smoke(args):
+    from repro.scenarios import get_scenario
+
+    res = await drive(get_scenario(args.scenario), seed=args.seed,
+                      host=args.host)
+    stats = res["stats"]
     print(json.dumps(dict(
-        submitted=n, answered=len(answered),
+        submitted=res["submitted"], answered=res["answered"],
         conservation=stats["conservation"],
         p50_latency_s=stats["p50_latency_s"],
         p95_latency_s=stats["p95_latency_s"],
-        ticks=stats["ticks"], ok=ok)))
-    if not ok:
+        ticks=stats["ticks"], ok=res["ok"])))
+    if not res["ok"]:
         raise SystemExit("serve smoke FAILED: "
-                         f"{len(answered)}/{n} answered, stats={stats}")
+                         f"{res['answered']}/{res['submitted']} answered, "
+                         f"stats={stats}")
     print("serve smoke OK", flush=True)
 
 
@@ -89,6 +109,8 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="run the CI smoke workload and exit")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     try:
         asyncio.run(_smoke(args) if args.smoke else _serve_forever(args))
     except KeyboardInterrupt:

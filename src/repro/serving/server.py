@@ -144,6 +144,10 @@ class LabelServer:
         self._closed = False
         self._server = None
         self._tick_task = None
+        self._close_task = None
+        self._stopped: Optional[asyncio.Event] = None
+        # the exception that killed the tick loop, if one did
+        self.error: Optional[BaseException] = None
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -154,13 +158,33 @@ class LabelServer:
         loop = asyncio.get_running_loop()
         self._work = asyncio.Event()
         self._drained = asyncio.Event()
+        self._stopped = asyncio.Event()
         self.state = await loop.run_in_executor(
             None, serve_init, self.cfg, self.seed)
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
         self._tick_task = asyncio.create_task(self._tick_loop())
+        self._tick_task.add_done_callback(self._on_tick_loop_done)
         return self
+
+    def _on_tick_loop_done(self, task):
+        """A tick loop that raised (a failed compile, a device error) stops
+        the server: waiters resolve as ``"shutdown"`` and
+        :meth:`wait_closed` re-raises, instead of HTTP answering forever
+        over a dead loop."""
+        if task.cancelled() or task.exception() is None:
+            return
+        self.error = task.exception()
+        self._close_task = asyncio.get_running_loop().create_task(
+            self.close(drain=False))
+
+    async def wait_closed(self):
+        """Block until the server has closed (``POST /shutdown`` or
+        :meth:`close`); raise the tick loop's exception if it died."""
+        await self._stopped.wait()
+        if self.error is not None:
+            raise RuntimeError("serve tick loop failed") from self.error
 
     async def close(self, *, drain: bool = True):
         """Graceful shutdown: stop accepting (new submissions get 503),
@@ -180,10 +204,8 @@ class LabelServer:
         self._closed = True
         if self._tick_task is not None:
             self._tick_task.cancel()
-            try:
-                await self._tick_task
-            except asyncio.CancelledError:
-                pass
+            # a failed loop's exception is kept in self.error
+            await asyncio.gather(self._tick_task, return_exceptions=True)
         for req in list(self._pending) + list(self._by_uid.values()):
             if req.status in ("pending", "queued"):
                 req.status = "shutdown"
@@ -193,6 +215,7 @@ class LabelServer:
         self._by_uid.clear()
         self._server.close()
         await self._server.wait_closed()
+        self._stopped.set()
 
     # ------------------------------------------------------------------
     # tick driver (continuous batching)

@@ -199,11 +199,22 @@ def collect() -> dict:
     lD = run_stream(to_stream_config(lmD), HORIZON, n_reps=N_REPS, seed=3)
     a, b = _common(l1, lD)
     report["lm_parity_sharded"] = _tree_equal(a, b)
+
+    # ---- chip_smoke.py's four-chip phase, rehearsed on host devices ----
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    four = smoke.phase_four_chips(4, horizon=60, n_reps=2, n_ticks=8)
+    report["chip_smoke_four_chips_ok"] = four["ok"]
     return report
 
 
 if __name__ == "__main__":
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault(
         "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_DEV}")
     json.dump(collect(), sys.stdout)

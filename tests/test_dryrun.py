@@ -13,7 +13,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run(args, timeout=900):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun"] + args,
         cwd=REPO, env=env, capture_output=True, text=True, timeout=timeout)

@@ -135,18 +135,6 @@ def test_entropy_vmapped(B, N, C):
                                    atol=1e-3, rtol=1e-2)
 
 
-@pytest.mark.tpu
-@pytest.mark.parametrize("N,C", [(512, 64), (1024, 48), (777, 17)])
-def test_entropy_learner_widths_mosaic(N, C):
-    """Real Mosaic lowering of the learner-width entropy path
-    (auto-skipped off-TPU)."""
-    x = jax.random.normal(jax.random.fold_in(KEY, N + C), (N, C)) * 3
-    out = entropy_scores(x)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.entropy_ref(x)),
-                               atol=1e-3, rtol=1e-2)
-
-
 def test_uncertainty_topk_selects_most_uncertain():
     from repro.kernels.ops import uncertainty_topk
     # rows with increasing temperature -> increasing entropy

@@ -147,25 +147,6 @@ def test_ds_em_with_kernel_estep_matches_jnp_path():
                                np.asarray(out_j["accuracy"]), atol=1e-4)
 
 
-@pytest.mark.tpu
-def test_ds_estep_kernel_mosaic():
-    """Real Mosaic lowering of the fused E-step (auto-skipped off-TPU)."""
-    from repro.kernels import ref
-    from repro.kernels.ds_estep import ds_estep
-    rng = np.random.default_rng(0)
-    W, C, T, V = 16, 8, 512, 5
-    R = W * C + 1
-    rows = np.log(rng.uniform(0.05, 0.95, (R, C))).astype(np.float32)
-    rows[-1] = 0.0
-    idx = rng.integers(0, R, (T, V)).astype(np.int32)
-    logp, post = ds_estep(jnp.array(rows), jnp.array(idx), interpret=False)
-    logp_r, post_r = ref.ds_estep_ref(jnp.array(rows), jnp.array(idx))
-    np.testing.assert_allclose(np.asarray(logp), np.asarray(logp_r),
-                               atol=1e-3)
-    np.testing.assert_allclose(np.asarray(post), np.asarray(post_r),
-                               atol=1e-4)
-
-
 def test_weighted_vote_boundary_accuracies():
     """Unanimous windows can push EM estimates to 0/1; the log-odds weights
     must stay finite and the vote well-defined."""
@@ -464,14 +445,6 @@ def test_hist_percentile_empty_histogram():
     assert s["p95_tis"] == float("inf")
 
 
-@pytest.mark.tpu
-def test_scored_match_tick_tpu():
-    """Real-backend lowering of the scored-match streaming tick (the scan
-    inside the vmapped tick); auto-skipped off-TPU."""
-    out = run_stream(HET_AWARE, 60, n_reps=2, seed=0)
-    assert int(np.asarray(out["arrived"]).sum()) >= 0
-
-
 @pytest.mark.slow
 def test_routing_soak_steady_state():
     """Long-horizon soak with worker-aware routing enabled: sustained
@@ -515,9 +488,11 @@ def test_learner_fused_redundancy_saves_votes_at_matched_accuracy():
     ds_only = _skewed()
     fused = _skewed(learner=StreamLearnerConfig(enabled=True,
                                                 min_votes_known=1))
-    s_ds = stream_summary(ds_only, run_stream(ds_only, HORIZON, n_reps=2,
-                                              seed=5))
-    s_lf = stream_summary(fused, run_stream(fused, HORIZON, n_reps=2,
+    # two horizons: ~130 finalized tasks, so one task is under the
+    # 0.02 accuracy margin
+    s_ds = stream_summary(ds_only, run_stream(ds_only, 2 * HORIZON,
+                                              n_reps=2, seed=5))
+    s_lf = stream_summary(fused, run_stream(fused, 2 * HORIZON, n_reps=2,
                                             seed=5))
     assert s_lf["votes_per_task"] <= 0.9 * s_ds["votes_per_task"], \
         (s_lf["votes_per_task"], s_ds["votes_per_task"])

@@ -284,3 +284,48 @@ def test_serve_tick_deterministic_fixed_seed():
     # the finalization stream actually finalized something
     total_fin = sum(int(np.asarray(o["fin"]).sum()) for o in outs_a)
     assert total_fin > 0
+
+
+def test_tick_loop_failure_stops_server_and_launcher(monkeypatch):
+    """A serve tick that raises (a failed compile, a device error) stops
+    the server: the waiting request resolves as "shutdown", and the
+    launcher's serve loop re-raises, so the process exits non-zero."""
+    import argparse
+
+    from repro.labelstream import router
+    from repro.launch import serve
+    from repro.serving.server import LabelServer, ServeClient
+
+    def broken_tick(*a, **kw):
+        raise FloatingPointError("tick failed")
+
+    monkeypatch.setattr(router, "serve_tick", broken_tick)
+    started = []
+    start = LabelServer.start
+
+    async def recording_start(self):
+        started.append(self)
+        return await start(self)
+
+    monkeypatch.setattr(LabelServer, "start", recording_start)
+    args = argparse.Namespace(scenario="serve_default", seed=0,
+                              host="127.0.0.1", port=0,
+                              tick_interval_s=0.0)
+
+    async def main():
+        loop_task = asyncio.create_task(serve._serve_forever(args))
+        while not started or started[0]._server is None:
+            await asyncio.sleep(0.01)
+        srv = started[0]
+        c = await ServeClient(srv.host, srv.port).connect()
+        status, r = await c.submit(wait=True, timeout_s=30.0)
+        await c.aclose()
+        with pytest.raises(RuntimeError, match="tick loop failed") as ei:
+            await asyncio.wait_for(loop_task, 30.0)
+        return status, r, ei.value, srv.stats()
+
+    status, r, err, stats = asyncio.run(main())
+    assert (status, r["status"]) == (202, "shutdown")
+    assert isinstance(err.__cause__, FloatingPointError)
+    assert stats["conservation"] is True
+    assert stats["shutdown_unanswered"] == 1

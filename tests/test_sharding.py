@@ -43,6 +43,9 @@ def report():
     if jax.device_count() >= 8:
         return _load_checks().collect()
     env = dict(os.environ)
+    # forced host devices exist only on the CPU backend, and this process
+    # may already hold an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     root = str(_CHECKS.parent.parent)
     env["PYTHONPATH"] = os.pathsep.join(
@@ -111,14 +114,10 @@ def test_embedding_bank_sharded_gather_parity(report):
     assert report["lm_parity_sharded"] is True
 
 
-@pytest.mark.tpu
-def test_sharded_parity_mosaic():
-    """Same parity invariant on real TPU devices (Mosaic lowering): the
-    shard-grouped tick must stay bit-identical to the single-device run
-    when the DS E-step goes through the fused Pallas kernel."""
-    rep = _load_checks().collect()
-    assert rep["parity_default"] is True
-    assert rep["conservation_ok"] is True
+def test_chip_smoke_four_chip_phase(report):
+    """chip_smoke.py --four-chips' comparison (4 devices against 1, every
+    output digest equal, conservation) passes on host devices."""
+    assert report["chip_smoke_four_chips_ok"] is True
 
 
 # --------------------------------------------------------------------------
