@@ -1,0 +1,6 @@
+"""95th percentile due-to-answer latency of the requests due in the window."""
+from readers import answer_pct_s
+
+
+def read(run):
+    return answer_pct_s(run, 95)

@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the chip."""
+from readers import device_idle_pct
+
+
+def read(run):
+    return device_idle_pct(run)
