@@ -50,9 +50,10 @@ def _encoder_throughput(bench, smoke):
     hard = rng.random(N) < 0.3
     tokens, lengths = make_tokens(ec, labels, hard, C, cfg.vocab_size, 2.0)
     run = lambda: np.asarray(encode(ec, tokens, lengths, 16, shard=False))
-    timing.timeit("embed.encode", run)      # cold: trace + XLA compile
-    timing.timeit("embed.encode", run)      # warm: execute only
-    row = [r for r in timing.summary() if r["name"] == "embed.encode"][0]
+    timing.timeit("bench.embed_encode", run)  # cold: trace + XLA compile
+    timing.timeit("bench.embed_encode", run)  # warm: execute only
+    row = [r for r in timing.summary()
+           if r["name"] == "bench.embed_encode"][0]
     cold_s = row["cold_s"]
     warm_s = row["warm_s"] or cold_s
     emit("embed_encode", 1e6 * warm_s / N,

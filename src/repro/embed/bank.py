@@ -24,6 +24,7 @@ from repro.embed.config import EmbedConfig
 from repro.embed.corpus import make_tokens
 from repro.embed.encoder import encode, resolved_config
 from repro.learning.features import standardize
+from repro.obs import timing
 
 
 class EmbeddingBank(NamedTuple):
@@ -94,16 +95,21 @@ def embed_texts(ec: EmbedConfig, texts, n_classes: int, n_features: int,
     then normalize with the BANK's pre-standardization statistics — not
     the batch's own — so one-off live submissions land on the same scale
     as the precomputed rows the learner was trained on. Returns an
-    ``(N, n_features)`` f32 array."""
+    ``(N, n_features)`` f32 array.
+
+    Spans (``repro.obs.timing``): ``embed.tokenize``, and ``embed.encode``
+    up to the encoder's return, which is on dispatch."""
     from repro.embed.corpus import tokenize_text
 
     bank = embedding_bank(ec, n_classes, n_features, class_sep,
                           hard_sep_scale)
     cfg = resolved_config(ec)
-    pairs = [tokenize_text(t, ec.seq_len, cfg.vocab_size) for t in texts]
-    tokens = np.stack([p[0] for p in pairs])
-    lengths = np.asarray([p[1] for p in pairs], np.int32)
-    E = encode(ec, tokens, lengths, n_features, shard=False)
+    with timing.span("embed.tokenize"):
+        pairs = [tokenize_text(t, ec.seq_len, cfg.vocab_size) for t in texts]
+        tokens = np.stack([p[0] for p in pairs])
+        lengths = np.asarray([p[1] for p in pairs], np.int32)
+    with timing.span("embed.encode"):
+        E = encode(ec, tokens, lengths, n_features, shard=False)
     return (E - bank.mean) / jnp.maximum(bank.std, 1e-6)
 
 

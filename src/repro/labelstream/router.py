@@ -69,6 +69,7 @@ from repro.core.simfast import (
     FastConfig, INF, PopTraced, _aot_timed, _init_workers, _uniform_block,
     churn_and_maintain, draw_latency, priority_match,
 )
+from repro.obs import timing
 from repro.obs.trace import PHASES as TRACE_PHASES
 from repro.obs.trace import TraceConfig
 from repro.labelstream.arrivals import (
@@ -415,211 +416,215 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
     up = _uniform_block(seed, step, 8 * P).reshape(8, P)
 
     # ---- backlog push + admission into free window slots -----------------
-    free = ~win["active"]
-    if cfg.batch_replay:
-        # naive fixed-batch replay: refill only once the window is drained
-        gate = free.all()
-    else:
-        gate = jnp.ones((), bool)
-    frank = (jnp.cumsum(free) - 1).astype(jnp.int32)
-    featw = None
-    if R.admission != "fifo":
-        # learner-driven admission: task identity (difficulty, true label,
-        # features) is drawn at ARRIVAL and stored in the slot-array
-        # backlog; admission ranks queued tasks by the current model's
-        # uncertainty on their features and takes the most uncertain first
-        # (an untrained model ties everything and slot order wins);
-        # "uncertain_learnable" weights uncertainty by the learnability
-        # head's estimate so chance-level-hard tasks stop hogging slots
-        F = L.n_features
-        occ = bl["occ"]
-        space = Q - occ.sum()
-        n_push = jnp.minimum(n_arr, space)
-        dropped = (n_arr - n_push).astype(jnp.int32)
-        slot = jnp.arange(M, dtype=jnp.int32)
-        # i-th arrival -> i-th free backlog slot (searchsorted rank trick)
-        csum = jnp.cumsum((~occ).astype(jnp.int32))
-        dst = jnp.searchsorted(csum, slot + 1).astype(jnp.int32)
-        ok = slot < n_push
-        dstw = jnp.where(ok, dst, Q)          # row Q is the dump row
-        ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
-                            (2 + 2 * F) * M).reshape(2 + 2 * F, M)
-        diff_a = jnp.where(ua[0] < ph, hs, 1.0)
-        tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
-        if L.feature_kind == "lm":
-            # the uniform the Gaussian path would spend on the first
-            # feature coordinate picks the bank variant instead — the
-            # diff/label/vote streams stay bit-identical across kinds
-            from repro.embed.bank import bank_gather
-            if labels_in is not None:
-                tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
-            feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
-            if feat_in is not None:
-                # injected real-text embeddings (serve mode) override the
-                # gathered synthetic ones; NaN rows mean "simulate"
-                feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
-                                   feat_in, feat_a)
+    with jax.named_scope("admission"):
+        free = ~win["active"]
+        if cfg.batch_replay:
+            # naive fixed-batch replay: refill only once the window is drained
+            gate = free.all()
         else:
-            feat_a = _task_features(ua[2:2 + F].T, ua[2 + F:2 + 2 * F].T,
-                                    tl_a, diff_a, L, C)
-        bl_times = bl["times"].at[dstw].set(t)
-        bl_diff = bl["diff"].at[dstw].set(diff_a)
-        bl_tlab = bl["tlab"].at[dstw].set(tl_a)
-        bl_feat = bl["feat"].at[dstw].set(feat_a)
-        if cfg.serve:
-            bl_uid = bl["uid"].at[dstw].set(uid_base + slot)
-        occ = jnp.concatenate([occ, jnp.zeros((1,), bool)]
-                              ).at[dstw].set(True)[:Q]
-        n_adm = jnp.where(gate, jnp.minimum(occ.sum(), free.sum()), 0
-                          ).astype(jnp.int32)
-        u_bl = uncertainty(bl_feat[:Q] @ lW + lb)
-        if R.admission == "uncertain_learnable":
-            adm_key = admit_scores(u_bl, bl_feat[:Q], gW, gb)
-        else:
-            adm_key = u_bl
-        admit_bl, order = admit_select(adm_key, occ, n_adm)
-        admit = free & (frank < n_adm)
-        # r-th free window slot takes the r-th most-uncertain queued task
-        src = jnp.where(admit, order[frank.clip(0, Q - 1)], Q)
-        arr_t = bl_times[src]
-        diff = bl_diff[src]
-        tl = bl_tlab[src]
-        featw = bl_feat[src]
-        occ = occ & ~admit_bl
-        bl = dict(times=bl_times, diff=bl_diff, tlab=bl_tlab, feat=bl_feat,
-                  occ=occ, count=occ.sum().astype(jnp.int32))
-        if cfg.serve:
-            uid_w = bl_uid[src]
-            bl["uid"] = bl_uid
-        bl_count = bl["count"]
-    else:
-        # FIFO ring of arrival times (PR-2 semantics, bit-for-bit)
-        lm_ring = cfg.serve and L.feature_kind == "lm"
-        space = Q - bl["count"]
-        n_push = jnp.minimum(n_arr, space)
-        dropped = (n_arr - n_push).astype(jnp.int32)
-        slot = jnp.arange(M, dtype=jnp.int32)
-        pos = (bl["head"] + bl["count"] + slot) % Q
-        posw = jnp.where(slot < n_push, pos, Q)
-        bl_times = bl["times"].at[posw].set(t)
-        if cfg.serve:
-            bl_uid = bl["uid"].at[posw].set(uid_base + slot)
-        if lm_ring:
-            # serve + lm binds identity at ARRIVAL: draw (or accept the
-            # injected) label/embedding now and ride the ring with it
-            from repro.embed.bank import bank_gather
+            gate = jnp.ones((), bool)
+        frank = (jnp.cumsum(free) - 1).astype(jnp.int32)
+        featw = None
+        if R.admission != "fifo":
+            # learner-driven admission: task identity (difficulty, true label,
+            # features) is drawn at ARRIVAL and stored in the slot-array
+            # backlog; admission ranks queued tasks by the current model's
+            # uncertainty on their features and takes the most uncertain first
+            # (an untrained model ties everything and slot order wins);
+            # "uncertain_learnable" weights uncertainty by the learnability
+            # head's estimate so chance-level-hard tasks stop hogging slots
+            F = L.n_features
+            occ = bl["occ"]
+            space = Q - occ.sum()
+            n_push = jnp.minimum(n_arr, space)
+            dropped = (n_arr - n_push).astype(jnp.int32)
+            slot = jnp.arange(M, dtype=jnp.int32)
+            # i-th arrival -> i-th free backlog slot (searchsorted rank trick)
+            csum = jnp.cumsum((~occ).astype(jnp.int32))
+            dst = jnp.searchsorted(csum, slot + 1).astype(jnp.int32)
+            ok = slot < n_push
+            dstw = jnp.where(ok, dst, Q)          # row Q is the dump row
             ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
-                                3 * M).reshape(3, M)
+                                (2 + 2 * F) * M).reshape(2 + 2 * F, M)
             diff_a = jnp.where(ua[0] < ph, hs, 1.0)
             tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
-            if labels_in is not None:
-                tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
-            feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
-            if feat_in is not None:
-                feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
-                                   feat_in, feat_a)
-            bl_tlab = bl["tlab"].at[posw].set(tl_a)
-            bl_diff = bl["diff"].at[posw].set(diff_a)
-            bl_feat = bl["feat"].at[posw].set(feat_a)
-        bl_count = bl["count"] + n_push
-        n_adm = jnp.where(gate, jnp.minimum(bl_count, free.sum()), 0
-                          ).astype(jnp.int32)
-        admit = free & (frank < n_adm)
-        src = jnp.where(admit, (bl["head"] + frank) % Q, Q)
-        arr_t = bl_times[src]
-        if cfg.serve:
-            uid_w = bl_uid[src]
-        bl = dict(times=bl_times, head=(bl["head"] + n_adm) % Q,
-                  count=bl_count - n_adm)
-        if cfg.serve:
-            bl["uid"] = bl_uid
-        bl_count = bl["count"]
-        if lm_ring:
-            bl["tlab"], bl["diff"], bl["feat"] = bl_tlab, bl_diff, bl_feat
+            if L.feature_kind == "lm":
+                # the uniform the Gaussian path would spend on the first
+                # feature coordinate picks the bank variant instead — the
+                # diff/label/vote streams stay bit-identical across kinds
+                from repro.embed.bank import bank_gather
+                if labels_in is not None:
+                    tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
+                feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
+                if feat_in is not None:
+                    # injected real-text embeddings (serve mode) override the
+                    # gathered synthetic ones; NaN rows mean "simulate"
+                    feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
+                                       feat_in, feat_a)
+            else:
+                feat_a = _task_features(ua[2:2 + F].T, ua[2 + F:2 + 2 * F].T,
+                                        tl_a, diff_a, L, C)
+            bl_times = bl["times"].at[dstw].set(t)
+            bl_diff = bl["diff"].at[dstw].set(diff_a)
+            bl_tlab = bl["tlab"].at[dstw].set(tl_a)
+            bl_feat = bl["feat"].at[dstw].set(feat_a)
+            if cfg.serve:
+                bl_uid = bl["uid"].at[dstw].set(uid_base + slot)
+            occ = jnp.concatenate([occ, jnp.zeros((1,), bool)]
+                                  ).at[dstw].set(True)[:Q]
+            n_adm = jnp.where(gate, jnp.minimum(occ.sum(), free.sum()), 0
+                              ).astype(jnp.int32)
+            u_bl = uncertainty(bl_feat[:Q] @ lW + lb)
+            if R.admission == "uncertain_learnable":
+                adm_key = admit_scores(u_bl, bl_feat[:Q], gW, gb)
+            else:
+                adm_key = u_bl
+            admit_bl, order = admit_select(adm_key, occ, n_adm)
+            admit = free & (frank < n_adm)
+            # r-th free window slot takes the r-th most-uncertain queued task
+            src = jnp.where(admit, order[frank.clip(0, Q - 1)], Q)
+            arr_t = bl_times[src]
             diff = bl_diff[src]
             tl = bl_tlab[src]
             featw = bl_feat[src]
+            occ = occ & ~admit_bl
+            bl = dict(times=bl_times, diff=bl_diff, tlab=bl_tlab, feat=bl_feat,
+                      occ=occ, count=occ.sum().astype(jnp.int32))
+            if cfg.serve:
+                uid_w = bl_uid[src]
+                bl["uid"] = bl_uid
+            bl_count = bl["count"]
         else:
-            # fresh-task draws at ADMISSION (difficulty mixture + label)
-            uw = _uniform_block(seed ^ jnp.uint32(0x33CC33CC), step, 2 * Ws
-                                ).reshape(2, Ws)
-            diff = jnp.where(uw[0] < ph, hs, 1.0)
-            tl = jnp.floor(uw[1] * C).astype(jnp.int32).clip(0, C - 1)
-            if L.enabled:
-                F = L.n_features
-                uf = _uniform_block(seed ^ jnp.uint32(0x5EEDF00D), step,
-                                    2 * Ws * F).reshape(2, Ws, F)
-                if L.feature_kind == "lm":
-                    # same-shaped block as the Gaussian draw; its first
-                    # column picks the bank variant, the rest is unread
-                    from repro.embed.bank import bank_gather
-                    featw = bank_gather(bank, uf[0, :, 0], tl, diff)
-                else:
-                    featw = _task_features(uf[0], uf[1], tl, diff, L, C)
-    win = dict(win)
-    win["active"] = win["active"] | admit
-    win["arrival_t"] = jnp.where(admit, arr_t, win["arrival_t"])
-    win["difficulty"] = jnp.where(admit, diff, win["difficulty"])
-    win["true_label"] = jnp.where(admit, tl, win["true_label"])
-    win["n_votes"] = jnp.where(admit, 0, win["n_votes"])
-    win["logpost"] = jnp.where(admit[:, None], 0.0, win["logpost"])
-    if L.enabled:
-        win["feat"] = jnp.where(admit[:, None], featw, win["feat"])
-    if cfg.serve:
-        win["uid"] = jnp.where(admit, uid_w, win["uid"])
-    tr = cfg.trace
-    tr_ph = tr is not None and tr.phases
-    if tr_ph:
-        win["admit_t"] = jnp.where(admit, t, win["admit_t"])
-        win["work_s"] = jnp.where(admit, 0.0, win["work_s"])
-        win["wait_s"] = jnp.where(admit, 0.0, win["wait_s"])
-        win["last_evt_t"] = jnp.where(admit, t, win["last_evt_t"])
+            # FIFO ring of arrival times (PR-2 semantics, bit-for-bit)
+            lm_ring = cfg.serve and L.feature_kind == "lm"
+            space = Q - bl["count"]
+            n_push = jnp.minimum(n_arr, space)
+            dropped = (n_arr - n_push).astype(jnp.int32)
+            slot = jnp.arange(M, dtype=jnp.int32)
+            pos = (bl["head"] + bl["count"] + slot) % Q
+            posw = jnp.where(slot < n_push, pos, Q)
+            bl_times = bl["times"].at[posw].set(t)
+            if cfg.serve:
+                bl_uid = bl["uid"].at[posw].set(uid_base + slot)
+            if lm_ring:
+                # serve + lm binds identity at ARRIVAL: draw (or accept the
+                # injected) label/embedding now and ride the ring with it
+                from repro.embed.bank import bank_gather
+                ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
+                                    3 * M).reshape(3, M)
+                diff_a = jnp.where(ua[0] < ph, hs, 1.0)
+                tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
+                if labels_in is not None:
+                    tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
+                feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
+                if feat_in is not None:
+                    feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
+                                       feat_in, feat_a)
+                bl_tlab = bl["tlab"].at[posw].set(tl_a)
+                bl_diff = bl["diff"].at[posw].set(diff_a)
+                bl_feat = bl["feat"].at[posw].set(feat_a)
+            bl_count = bl["count"] + n_push
+            n_adm = jnp.where(gate, jnp.minimum(bl_count, free.sum()), 0
+                              ).astype(jnp.int32)
+            admit = free & (frank < n_adm)
+            src = jnp.where(admit, (bl["head"] + frank) % Q, Q)
+            arr_t = bl_times[src]
+            if cfg.serve:
+                uid_w = bl_uid[src]
+            bl = dict(times=bl_times, head=(bl["head"] + n_adm) % Q,
+                      count=bl_count - n_adm)
+            if cfg.serve:
+                bl["uid"] = bl_uid
+            bl_count = bl["count"]
+            if lm_ring:
+                bl["tlab"], bl["diff"], bl["feat"] = bl_tlab, bl_diff, bl_feat
+                diff = bl_diff[src]
+                tl = bl_tlab[src]
+                featw = bl_feat[src]
+            else:
+                # fresh-task draws at ADMISSION (difficulty mixture + label)
+                uw = _uniform_block(seed ^ jnp.uint32(0x33CC33CC), step, 2 * Ws
+                                    ).reshape(2, Ws)
+                diff = jnp.where(uw[0] < ph, hs, 1.0)
+                tl = jnp.floor(uw[1] * C).astype(jnp.int32).clip(0, C - 1)
+                if L.enabled:
+                    F = L.n_features
+                    uf = _uniform_block(seed ^ jnp.uint32(0x5EEDF00D), step,
+                                        2 * Ws * F).reshape(2, Ws, F)
+                    if L.feature_kind == "lm":
+                        # same-shaped block as the Gaussian draw; its first
+                        # column picks the bank variant, the rest is unread
+                        from repro.embed.bank import bank_gather
+                        featw = bank_gather(bank, uf[0, :, 0], tl, diff)
+                    else:
+                        featw = _task_features(uf[0], uf[1], tl, diff, L, C)
+        win = dict(win)
+        win["active"] = win["active"] | admit
+        win["arrival_t"] = jnp.where(admit, arr_t, win["arrival_t"])
+        win["difficulty"] = jnp.where(admit, diff, win["difficulty"])
+        win["true_label"] = jnp.where(admit, tl, win["true_label"])
+        win["n_votes"] = jnp.where(admit, 0, win["n_votes"])
+        win["logpost"] = jnp.where(admit[:, None], 0.0, win["logpost"])
+        if L.enabled:
+            win["feat"] = jnp.where(admit[:, None], featw, win["feat"])
+        if cfg.serve:
+            win["uid"] = jnp.where(admit, uid_w, win["uid"])
+        tr = cfg.trace
+        tr_ph = tr is not None and tr.phases
+        if tr_ph:
+            win["admit_t"] = jnp.where(admit, t, win["admit_t"])
+            win["work_s"] = jnp.where(admit, 0.0, win["work_s"])
+            win["wait_s"] = jnp.where(admit, 0.0, win["wait_s"])
+            win["last_evt_t"] = jnp.where(admit, t, win["last_evt_t"])
 
     # ---- completions -> votes -> online posterior -----------------------
-    ws = dict(ws)
-    active_w = ws["assigned"] >= 0
-    comp = active_w & (ws["busy_until"] <= t)
-    a_idx = jnp.maximum(ws["assigned"], 0)
-    tid = jnp.where(comp, ws["assigned"], Ws)
-    lat = jnp.where(comp, ws["busy_until"] - ws["start_t"], 0.0)
-    d_w = win["difficulty"][a_idx]
-    p_corr = jnp.clip(1.0 / C + (ws["acc"] - 1.0 / C) * d_w, 1.0 / C, 0.995)
-    tl_w = win["true_label"][a_idx]
-    correct = up[0] < p_corr
-    wrong = jnp.floor(up[1] * max(C - 1, 1)).astype(jnp.int32)
-    label = jnp.where(correct, tl_w,
-                      jnp.where(wrong >= tl_w, wrong + 1, wrong))
-    # vote slot position: n_votes before this tick + rank among this tick's
-    # completions of the same task; votes landing past the cap are dropped
-    # (paid straggler duplicates that lost the race to the budget)
-    pr = jnp.arange(P)
-    prior_ct = ((tid[None, :] == tid[:, None]) & comp[None, :]
-                & (pr[None, :] < pr[:, None])).sum(-1).astype(jnp.int32)
-    vpos = win["n_votes"][a_idx] + prior_ct
-    keep = comp & (vpos < cap_t)
-    tid_k = jnp.where(keep, tid, Ws)
-    vpos_k = jnp.where(keep, vpos, 0).clip(0, cap - 1)
-    win["vote_wid"] = win["vote_wid"].at[tid_k, vpos_k].set(
-        jnp.where(keep, pr, win["vote_wid"][tid_k, vpos_k]))
-    win["vote_lab"] = win["vote_lab"].at[tid_k, vpos_k].set(
-        jnp.where(keep, label, win["vote_lab"][tid_k, vpos_k]))
-    # online DS E-step: add the voter's estimated log-odds to the voted class
-    a_e = _acc_hat(cfg, ws)
-    delta = jnp.log(a_e * max(C - 1, 1) / (1.0 - a_e))
-    win["logpost"] = (jnp.concatenate(
-        [win["logpost"], jnp.zeros((1, C))])
-        .at[tid_k, label].add(jnp.where(keep, delta, 0.0)))[:Ws]
-    win["n_votes"] = (jnp.concatenate([win["n_votes"], jnp.zeros((1,),
-                                                                 jnp.int32)])
-                      .at[tid_k].add(keep.astype(jnp.int32)))[:Ws]
-    if tr_ph:
-        # completion instant of this tick's credited votes (busy_until
-        # still holds it here; the slot is reset to INF only after the
-        # worker-bookkeeping block below) — the finalize lag measures
-        # from the LAST evidence the posterior saw
-        win["last_evt_t"] = (jnp.concatenate(
-            [win["last_evt_t"], jnp.zeros((1,))])
-            .at[tid_k].max(jnp.where(keep, ws["busy_until"], -INF)))[:Ws]
+    with jax.named_scope("votes"):
+        ws = dict(ws)
+        active_w = ws["assigned"] >= 0
+        comp = active_w & (ws["busy_until"] <= t)
+        a_idx = jnp.maximum(ws["assigned"], 0)
+        tid = jnp.where(comp, ws["assigned"], Ws)
+        lat = jnp.where(comp, ws["busy_until"] - ws["start_t"], 0.0)
+        d_w = win["difficulty"][a_idx]
+        p_corr = jnp.clip(1.0 / C + (ws["acc"] - 1.0 / C) * d_w, 1.0 / C,
+                          0.995)
+        tl_w = win["true_label"][a_idx]
+        correct = up[0] < p_corr
+        wrong = jnp.floor(up[1] * max(C - 1, 1)).astype(jnp.int32)
+        label = jnp.where(correct, tl_w,
+                          jnp.where(wrong >= tl_w, wrong + 1, wrong))
+        # vote slot position: n_votes before this tick + rank among this tick's
+        # completions of the same task; votes landing past the cap are dropped
+        # (paid straggler duplicates that lost the race to the budget)
+        pr = jnp.arange(P)
+        prior_ct = ((tid[None, :] == tid[:, None]) & comp[None, :]
+                    & (pr[None, :] < pr[:, None])).sum(-1).astype(jnp.int32)
+        vpos = win["n_votes"][a_idx] + prior_ct
+        keep = comp & (vpos < cap_t)
+        tid_k = jnp.where(keep, tid, Ws)
+        vpos_k = jnp.where(keep, vpos, 0).clip(0, cap - 1)
+        win["vote_wid"] = win["vote_wid"].at[tid_k, vpos_k].set(
+            jnp.where(keep, pr, win["vote_wid"][tid_k, vpos_k]))
+        win["vote_lab"] = win["vote_lab"].at[tid_k, vpos_k].set(
+            jnp.where(keep, label, win["vote_lab"][tid_k, vpos_k]))
+        # online DS E-step: add the voter's estimated log-odds to the voted
+        # class
+        a_e = _acc_hat(cfg, ws)
+        delta = jnp.log(a_e * max(C - 1, 1) / (1.0 - a_e))
+        win["logpost"] = (jnp.concatenate(
+            [win["logpost"], jnp.zeros((1, C))])
+            .at[tid_k, label].add(jnp.where(keep, delta, 0.0)))[:Ws]
+        win["n_votes"] = (jnp.concatenate(
+            [win["n_votes"], jnp.zeros((1,), jnp.int32)])
+            .at[tid_k].add(keep.astype(jnp.int32)))[:Ws]
+        if tr_ph:
+            # completion instant of this tick's credited votes (busy_until
+            # still holds it here; the slot is reset to INF only after the
+            # worker-bookkeeping block below) — the finalize lag measures
+            # from the LAST evidence the posterior saw
+            win["last_evt_t"] = (jnp.concatenate(
+                [win["last_evt_t"], jnp.zeros((1,))])
+                .at[tid_k].max(jnp.where(keep, ws["busy_until"], -INF)))[:Ws]
 
     # ---- periodic offline full-confusion Dawid-Skene refresh ------------
     # every refresh_every ticks, re-run the exact batched EM (aggregate.py)
@@ -627,193 +632,204 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
     # accuracy estimates from it — the online one-coin increments drift
     # (stale accuracy estimates at vote time are never revisited); the
     # offline EM re-explains every stored vote under the final confusions
-    if cfg.refresh_every > 0:
-        from repro.labelstream.aggregate import _ds_em, estep_mode
+    with jax.named_scope("refresh"):
+        if cfg.refresh_every > 0:
+            from repro.labelstream.aggregate import _ds_em, estep_mode
 
-        use_kernel, interpret = estep_mode()
+            use_kernel, interpret = estep_mode()
 
-        def _refresh(_):
-            vmask_r = (jnp.arange(cap)[None, :] < win["n_votes"][:, None]) \
-                & win["active"][:, None]
-            em = _ds_em(win["vote_lab"][:Ws], win["vote_wid"][:Ws], vmask_r,
-                        P + 1, C, cfg.refresh_iters, False, use_kernel,
-                        interpret)
-            lp = jnp.where((win["active"] & (win["n_votes"] > 0))[:, None],
-                           em["log_posterior"], win["logpost"])
-            vpw = em["votes_per_worker"][:P]
-            return lp, em["accuracy"][:P] * vpw, vpw
+            def _refresh(_):
+                vmask_r = (jnp.arange(cap)[None, :]
+                           < win["n_votes"][:, None]) \
+                    & win["active"][:, None]
+                em = _ds_em(win["vote_lab"][:Ws], win["vote_wid"][:Ws],
+                            vmask_r, P + 1, C, cfg.refresh_iters, False,
+                            use_kernel, interpret)
+                lp = jnp.where((win["active"] & (win["n_votes"] > 0))[:, None],
+                               em["log_posterior"], win["logpost"])
+                vpw = em["votes_per_worker"][:P]
+                return lp, em["accuracy"][:P] * vpw, vpw
 
-        win["logpost"], ws["est_correct"], ws["est_n"] = jax.lax.cond(
-            step % cfg.refresh_every == cfg.refresh_every - 1, _refresh,
-            lambda _: (win["logpost"], ws["est_correct"], ws["est_n"]),
-            None)
+            win["logpost"], ws["est_correct"], ws["est_n"] = jax.lax.cond(
+                step % cfg.refresh_every == cfg.refresh_every - 1, _refresh,
+                lambda _: (win["logpost"], ws["est_correct"], ws["est_n"]),
+                None)
 
     # ---- learner fusion (product of experts) ----------------------------
     # the adaptive-redundancy policy consumes the learner posterior fused
     # with the DS posterior: tasks the model already knows finalize after
     # min_votes_known crowd votes and stop soliciting further votes
-    if L.enabled:
-        model_lp = jax.nn.log_softmax(win["feat"] @ lW + lb, axis=-1)
-        fused = fuse_posteriors(win["logpost"], model_lp, fuse_w)
-        known, known_fin = learner_known(
-            fused, win["n_votes"], threshold=L.known_threshold,
-            min_votes_known=L.min_votes_known)
-    else:
-        fused = win["logpost"]
-        known = jnp.zeros((Ws,), bool)
-        known_fin = known
+    with jax.named_scope("fusion"):
+        if L.enabled:
+            model_lp = jax.nn.log_softmax(win["feat"] @ lW + lb, axis=-1)
+            fused = fuse_posteriors(win["logpost"], model_lp, fuse_w)
+            known, known_fin = learner_known(
+                fused, win["n_votes"], threshold=L.known_threshold,
+                min_votes_known=L.min_votes_known)
+        else:
+            fused = win["logpost"]
+            known = jnp.zeros((Ws,), bool)
+            known_fin = known
 
     # ---- finalization (adaptive redundancy) -----------------------------
-    fin, conf = should_finalize(fused, win["n_votes"], pol, cap=cap_eff)
-    fin = (fin | known_fin) & win["active"]
-    result = fused.argmax(-1)
-    tis = jnp.where(fin, t - win["arrival_t"], 0.0)
-    # steady-state metrics count tasks by ARRIVAL-time warmth (matching the
-    # offered-rate gate), so warmup queueing cannot leak into the histogram
-    # and sustained throughput is measured against the same task population
-    wfin = fin & (win["arrival_t"] >= warmup_t)
-    nbin = cfg.tis_bins
-    hbin = jnp.clip((tis / cfg.tis_bin_s).astype(jnp.int32), 0, nbin - 1)
-    hist_d = jnp.zeros((nbin + 1,), jnp.int32).at[
-        jnp.where(wfin, hbin, nbin)].add(1)[:nbin]
-    done_d = wfin.sum()
-    corr_d = (wfin & (result == win["true_label"])).sum()
-    tis_d = (tis * wfin).sum()
-    votesfin_d = (win["n_votes"] * wfin).sum()
-    if tr_ph:
-        # latency-source decomposition at finalize time (paper §2's
-        # taxonomy, Table-1-style): backlog_wait + window_wait + work_time
-        # == time-in-system exactly (tick accounting below), finalize_lag
-        # is the overlapping tail past the last posterior evidence
-        ph_vals = dict(
-            backlog_wait=win["admit_t"] - win["arrival_t"],
-            window_wait=win["wait_s"],
-            work_time=win["work_s"],
-            finalize_lag=jnp.clip(t - win["last_evt_t"], 0.0, None),
-        )
-        ph_hist = {}
-        ph_sum = {}
-        for pk in TRACE_PHASES:
-            pb = jnp.clip((ph_vals[pk] / cfg.tis_bin_s).astype(jnp.int32),
-                          0, nbin - 1)
-            ph_hist[pk] = jnp.zeros((nbin + 1,), jnp.int32).at[
-                jnp.where(wfin, pb, nbin)].add(1)[:nbin]
-            ph_sum[pk] = (ph_vals[pk] * wfin).sum()
+    with jax.named_scope("finalize"):
+        fin, conf = should_finalize(fused, win["n_votes"], pol, cap=cap_eff)
+        fin = (fin | known_fin) & win["active"]
+        result = fused.argmax(-1)
+        tis = jnp.where(fin, t - win["arrival_t"], 0.0)
+        # steady-state metrics count tasks by ARRIVAL-time warmth (matching the
+        # offered-rate gate), so warmup queueing cannot leak into the histogram
+        # and sustained throughput is measured against the same task population
+        wfin = fin & (win["arrival_t"] >= warmup_t)
+        nbin = cfg.tis_bins
+        hbin = jnp.clip((tis / cfg.tis_bin_s).astype(jnp.int32), 0, nbin - 1)
+        hist_d = jnp.zeros((nbin + 1,), jnp.int32).at[
+            jnp.where(wfin, hbin, nbin)].add(1)[:nbin]
+        done_d = wfin.sum()
+        corr_d = (wfin & (result == win["true_label"])).sum()
+        tis_d = (tis * wfin).sum()
+        votesfin_d = (win["n_votes"] * wfin).sum()
+        if tr_ph:
+            # latency-source decomposition at finalize time (paper §2's
+            # taxonomy, Table-1-style): backlog_wait + window_wait + work_time
+            # == time-in-system exactly (tick accounting below), finalize_lag
+            # is the overlapping tail past the last posterior evidence
+            ph_vals = dict(
+                backlog_wait=win["admit_t"] - win["arrival_t"],
+                window_wait=win["wait_s"],
+                work_time=win["work_s"],
+                finalize_lag=jnp.clip(t - win["last_evt_t"], 0.0, None),
+            )
+            ph_hist = {}
+            ph_sum = {}
+            for pk in TRACE_PHASES:
+                pb = jnp.clip((ph_vals[pk] / cfg.tis_bin_s).astype(jnp.int32),
+                              0, nbin - 1)
+                ph_hist[pk] = jnp.zeros((nbin + 1,), jnp.int32).at[
+                    jnp.where(wfin, pb, nbin)].add(1)[:nbin]
+                ph_sum[pk] = (ph_vals[pk] * wfin).sum()
     # credit voters of finalized tasks by agreement with the final label
     # (incremental hard-EM M-step for the online accuracy estimates)
-    vmask = (jnp.arange(cap)[None, :] < win["n_votes"][:Ws, None]) \
-        & fin[:, None]
-    vw = jnp.where(vmask, win["vote_wid"][:Ws], P)
-    agree = (win["vote_lab"][:Ws] == result[:, None]) & vmask
-    ws["est_correct"] = ws["est_correct"] + jnp.zeros((P + 1,)).at[
-        vw.reshape(-1)].add(agree.reshape(-1).astype(jnp.float32))[:P]
-    ws["est_n"] = ws["est_n"] + jnp.zeros((P + 1,)).at[
-        vw.reshape(-1)].add(vmask.reshape(-1).astype(jnp.float32))[:P]
-    win["active"] = win["active"] & ~fin
+    with jax.named_scope("credit"):
+        vmask = (jnp.arange(cap)[None, :] < win["n_votes"][:Ws, None]) \
+            & fin[:, None]
+        vw = jnp.where(vmask, win["vote_wid"][:Ws], P)
+        agree = (win["vote_lab"][:Ws] == result[:, None]) & vmask
+        ws["est_correct"] = ws["est_correct"] + jnp.zeros((P + 1,)).at[
+            vw.reshape(-1)].add(agree.reshape(-1).astype(jnp.float32))[:P]
+        ws["est_n"] = ws["est_n"] + jnp.zeros((P + 1,)).at[
+            vw.reshape(-1)].add(vmask.reshape(-1).astype(jnp.float32))[:P]
+        win["active"] = win["active"] & ~fin
 
     # ---- worker bookkeeping: completers + straggler losers --------------
-    lose = active_w & ~comp & fin[a_idx]
-    win_lat = jnp.zeros((Ws + 1,)).at[tid].max(lat)[:Ws]
-    winner = jnp.where(lose, win_lat[a_idx], 0.0)
-    freed = comp | lose
-    ws["n_completed"] = ws["n_completed"] + comp
-    ws["n_terminated"] = ws["n_terminated"] + lose
-    ws["comp_sum"] = ws["comp_sum"] + lat * comp
-    ws["comp_sqsum"] = ws["comp_sqsum"] + lat * lat * comp
-    ws["term_sum"] = ws["term_sum"] + winner * lose
-    # completion-latency EWMA: the routing speed axis (route_scores)
-    ws["lat_ewma"] = jnp.where(
-        comp, (1.0 - R.ewma_alpha) * ws["lat_ewma"] + R.ewma_alpha * lat,
-        ws["lat_ewma"])
-    ws["cost_work"] = ws["cost_work"] + freed.sum() * WORK_PAY_PER_RECORD
-    ws["blocked_until"] = jnp.where(
-        comp, ws["busy_until"],
-        jnp.where(lose, t + SWITCH_DELAY_S, ws["blocked_until"]))
-    ws["assigned"] = jnp.where(freed, -1, ws["assigned"])
-    ws["busy_until"] = jnp.where(freed, INF, ws["busy_until"])
+    with jax.named_scope("bookkeeping"):
+        lose = active_w & ~comp & fin[a_idx]
+        win_lat = jnp.zeros((Ws + 1,)).at[tid].max(lat)[:Ws]
+        winner = jnp.where(lose, win_lat[a_idx], 0.0)
+        freed = comp | lose
+        ws["n_completed"] = ws["n_completed"] + comp
+        ws["n_terminated"] = ws["n_terminated"] + lose
+        ws["comp_sum"] = ws["comp_sum"] + lat * comp
+        ws["comp_sqsum"] = ws["comp_sqsum"] + lat * lat * comp
+        ws["term_sum"] = ws["term_sum"] + winner * lose
+        # completion-latency EWMA: the routing speed axis (route_scores)
+        ws["lat_ewma"] = jnp.where(
+            comp, (1.0 - R.ewma_alpha) * ws["lat_ewma"] + R.ewma_alpha * lat,
+            ws["lat_ewma"])
+        ws["cost_work"] = ws["cost_work"] + freed.sum() * WORK_PAY_PER_RECORD
+        ws["blocked_until"] = jnp.where(
+            comp, ws["busy_until"],
+            jnp.where(lose, t + SWITCH_DELAY_S, ws["blocked_until"]))
+        ws["assigned"] = jnp.where(freed, -1, ws["assigned"])
+        ws["busy_until"] = jnp.where(freed, INF, ws["busy_until"])
 
     # ---- churn + latency maintenance (shared simfast machinery) ---------
-    ws, leave = churn_and_maintain(fast, ws, banks, t, up[2], up[3],
-                                   cfg.recruit_mean_s)
-    ws["est_correct"] = jnp.where(leave, 0.0, ws["est_correct"])
-    ws["est_n"] = jnp.where(leave, 0.0, ws["est_n"])
-    ws["lat_ewma"] = jnp.where(leave, cfg.median_mu, ws["lat_ewma"])
-    # stored votes key on the pool slot: remap votes cast by departing
-    # workers to the dump slot P so finalize-time crediting cannot charge
-    # the replacement worker for its predecessor's answers
-    leave_pad = jnp.concatenate([leave, jnp.zeros((1,), bool)])
-    win["vote_wid"] = jnp.where(leave_pad[win["vote_wid"]], P,
-                                win["vote_wid"])
+    with jax.named_scope("maintenance"):
+        ws, leave = churn_and_maintain(fast, ws, banks, t, up[2], up[3],
+                                       cfg.recruit_mean_s)
+        ws["est_correct"] = jnp.where(leave, 0.0, ws["est_correct"])
+        ws["est_n"] = jnp.where(leave, 0.0, ws["est_n"])
+        ws["lat_ewma"] = jnp.where(leave, cfg.median_mu, ws["lat_ewma"])
+        # stored votes key on the pool slot: remap votes cast by departing
+        # workers to the dump slot P so finalize-time crediting cannot charge
+        # the replacement worker for its predecessor's answers
+        leave_pad = jnp.concatenate([leave, jnp.zeros((1,), bool)])
+        win["vote_wid"] = jnp.where(leave_pad[win["vote_wid"]], P,
+                                    win["vote_wid"])
 
     # ---- assignment: understaffed tasks first, then duplicates ----------
-    avail = (ws["assigned"] < 0) & (ws["blocked_until"] <= t) \
-        & (ws["session_end"] > t)
-    n_asg = jnp.zeros((Ws + 1,), jnp.int32).at[
-        jnp.where(ws["assigned"] >= 0, ws["assigned"], Ws)].add(1)[:Ws]
-    want = target_outstanding(win["n_votes"], pol, cap=cap_eff)
-    if L.enabled:
-        # a model-known task requests only the crowd votes it still needs
-        # to clear the min_votes_known floor — the learner posterior covers
-        # the rest, so the saved votes concentrate on unknown tasks
-        want = jnp.where(known, jnp.minimum(
-            want, jnp.maximum(L.min_votes_known - win["n_votes"], 0)), want)
-    tier1 = win["active"] & (n_asg < want)
-    if cfg.straggler:
-        extra = jnp.minimum(want, cfg.max_dup)
-        tier2 = win["active"] & (want > 0) & (n_asg >= want) \
-            & (n_asg < want + extra)
-    else:
-        tier2 = jnp.zeros((Ws,), bool)
-    if R.enabled:
-        # FROG-style worker-aware routing: score workers x window slots
-        # from the ONLINE per-worker accuracy estimate (the same counters
-        # behind the DS vote weights, refreshed after this tick's
-        # crediting and churn) and the completion-latency EWMA, then
-        # greedy-match under scan. Task uncertainty comes from the FUSED
-        # posterior, so an enabled learner sharpens the routing for free;
-        # with w_acc == w_speed == 0 this is exactly priority_match
-        shift = (_uniform_block(seed ^ jnp.uint32(0xA5A5A5A5), step, 1)[0]
-                 * Ws).astype(jnp.int32)
-        scores = route_scores(_acc_hat(cfg, ws), ws["lat_ewma"],
-                              uncertainty(fused), R)
-        take, task_for_w, _, _ = scored_match(scores, avail, tier1, tier2,
-                                              shift)
-    elif L.enabled and L.prioritize:
-        # learner-driven prioritization: route votes to the window tasks
-        # with the LOWEST fused confidence first (priority_match drains
-        # eligible tasks in slot order, so matching in permuted slot space
-        # and mapping back yields most-uncertain-first routing)
-        unc = jnp.where(win["active"], -confidence(fused), -jnp.inf)
-        perm = jnp.argsort(-unc, stable=True).astype(jnp.int32)
-        take, task_p, _, _ = priority_match(
-            avail, tier1[perm], tier2[perm], jnp.zeros((), jnp.int32))
-        task_for_w = perm[task_p]
-    else:
-        shift = (_uniform_block(seed ^ jnp.uint32(0xA5A5A5A5), step, 1)[0]
-                 * Ws).astype(jnp.int32)
-        take, task_for_w, _, _ = priority_match(avail, tier1, tier2, shift)
-    lat_new = draw_latency(fast, ws["mu"], ws["sigma"], up[6], up[7])
-    ws["assigned"] = jnp.where(take, task_for_w, ws["assigned"])
-    ws["busy_until"] = jnp.where(take, t + lat_new, ws["busy_until"])
-    ws["start_t"] = jnp.where(take, t, ws["start_t"])
-    ws["n_started"] = ws["n_started"] + take
-    waiting = avail & ~take
-    ws["cost_wait"] = ws["cost_wait"] + waiting.sum() * cfg.dt * WAIT_PAY_PER_S
-
-    if tr_ph:
-        # attribute this tick to work vs wait for every still-active task:
-        # staffed (>= 1 assigned worker after this tick's matching) ticks
-        # count as work time, active-but-unstaffed ticks as window wait.
-        # A task admitted at tick k and finalized at tick k+m accumulates
-        # exactly m ticks here (its finalize tick doesn't count: the slot
-        # already left "active" above), so backlog_wait + window_wait +
-        # work_time == time-in-system exactly
-        n_asg_post = jnp.zeros((Ws + 1,), jnp.int32).at[
+    with jax.named_scope("assign"):
+        avail = (ws["assigned"] < 0) & (ws["blocked_until"] <= t) \
+            & (ws["session_end"] > t)
+        n_asg = jnp.zeros((Ws + 1,), jnp.int32).at[
             jnp.where(ws["assigned"] >= 0, ws["assigned"], Ws)].add(1)[:Ws]
-        staffed = win["active"] & (n_asg_post > 0)
-        win["work_s"] = win["work_s"] + jnp.where(staffed, cfg.dt, 0.0)
-        win["wait_s"] = win["wait_s"] + jnp.where(
-            win["active"] & ~staffed, cfg.dt, 0.0)
+        want = target_outstanding(win["n_votes"], pol, cap=cap_eff)
+        if L.enabled:
+            # a model-known task requests only the crowd votes it still needs
+            # to clear the min_votes_known floor — the learner posterior
+            # covers the rest, so the saved votes concentrate on unknown
+            # tasks
+            want = jnp.where(known, jnp.minimum(
+                want, jnp.maximum(L.min_votes_known - win["n_votes"], 0)),
+                want)
+        tier1 = win["active"] & (n_asg < want)
+        if cfg.straggler:
+            extra = jnp.minimum(want, cfg.max_dup)
+            tier2 = win["active"] & (want > 0) & (n_asg >= want) \
+                & (n_asg < want + extra)
+        else:
+            tier2 = jnp.zeros((Ws,), bool)
+        if R.enabled:
+            # FROG-style worker-aware routing: score workers x window slots
+            # from the ONLINE per-worker accuracy estimate (the same counters
+            # behind the DS vote weights, refreshed after this tick's
+            # crediting and churn) and the completion-latency EWMA, then
+            # greedy-match under scan. Task uncertainty comes from the FUSED
+            # posterior, so an enabled learner sharpens the routing for free;
+            # with w_acc == w_speed == 0 this is exactly priority_match
+            shift = (_uniform_block(seed ^ jnp.uint32(0xA5A5A5A5), step, 1)[0]
+                     * Ws).astype(jnp.int32)
+            scores = route_scores(_acc_hat(cfg, ws), ws["lat_ewma"],
+                                  uncertainty(fused), R)
+            take, task_for_w, _, _ = scored_match(scores, avail, tier1, tier2,
+                                                  shift)
+        elif L.enabled and L.prioritize:
+            # learner-driven prioritization: route votes to the window tasks
+            # with the LOWEST fused confidence first (priority_match drains
+            # eligible tasks in slot order, so matching in permuted slot space
+            # and mapping back yields most-uncertain-first routing)
+            unc = jnp.where(win["active"], -confidence(fused), -jnp.inf)
+            perm = jnp.argsort(-unc, stable=True).astype(jnp.int32)
+            take, task_p, _, _ = priority_match(
+                avail, tier1[perm], tier2[perm], jnp.zeros((), jnp.int32))
+            task_for_w = perm[task_p]
+        else:
+            shift = (_uniform_block(seed ^ jnp.uint32(0xA5A5A5A5), step, 1)[0]
+                     * Ws).astype(jnp.int32)
+            take, task_for_w, _, _ = priority_match(avail, tier1, tier2, shift)
+        lat_new = draw_latency(fast, ws["mu"], ws["sigma"], up[6], up[7])
+        ws["assigned"] = jnp.where(take, task_for_w, ws["assigned"])
+        ws["busy_until"] = jnp.where(take, t + lat_new, ws["busy_until"])
+        ws["start_t"] = jnp.where(take, t, ws["start_t"])
+        ws["n_started"] = ws["n_started"] + take
+        waiting = avail & ~take
+        ws["cost_wait"] = ws["cost_wait"] \
+            + waiting.sum() * cfg.dt * WAIT_PAY_PER_S
+
+        if tr_ph:
+            # attribute this tick to work vs wait for every still-active task:
+            # staffed (>= 1 assigned worker after this tick's matching) ticks
+            # count as work time, active-but-unstaffed ticks as window wait.
+            # A task admitted at tick k and finalized at tick k+m accumulates
+            # exactly m ticks here (its finalize tick doesn't count: the slot
+            # already left "active" above), so backlog_wait + window_wait +
+            # work_time == time-in-system exactly
+            n_asg_post = jnp.zeros((Ws + 1,), jnp.int32).at[
+                jnp.where(ws["assigned"] >= 0, ws["assigned"], Ws)].add(1)[:Ws]
+            staffed = win["active"] & (n_asg_post > 0)
+            win["work_s"] = win["work_s"] + jnp.where(staffed, cfg.dt, 0.0)
+            win["wait_s"] = win["wait_s"] + jnp.where(
+                win["active"] & ~staffed, cfg.dt, 0.0)
 
     metrics = dict(hist=hist_d, done=done_d, correct=corr_d, sum_tis=tis_d,
                    votes_fin=votesfin_d,
@@ -1811,7 +1827,8 @@ def _serve_tick_impl(cfg: StreamConfig, state, n_arr, uid_base,
         return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
 
     t, step = state["t"], state["step"]
-    lW, lb, fuse_w, gW, gb = _learner_tick_params(cfg, state)
+    with jax.named_scope("learner"):
+        lW, lb, fuse_w, gW, gb = _learner_tick_params(cfg, state)
     if cfg.learner.feature_kind == "lm":
         ws, win, bl, m, train = jax.vmap(
             lambda w, bk, wi, b, na, ub, fi, li, sd: _shard_tick(
@@ -1828,13 +1845,15 @@ def _serve_tick_impl(cfg: StreamConfig, state, n_arr, uid_base,
         )(state["ws"], state["banks"], state["win"], state["bl"],
           n_arr, uid_base, state["seeds"])
 
-    if sh.steal != "none":
-        bl, got, gave = _steal_rebalance(cfg, bl, lo, axis_name)
-    else:
-        got = gave = jnp.zeros((Sl,), jnp.int32)
+    with jax.named_scope("steal"):
+        if sh.steal != "none":
+            bl, got, gave = _steal_rebalance(cfg, bl, lo, axis_name)
+        else:
+            got = gave = jnp.zeros((Sl,), jnp.int32)
 
     new = dict(state)
-    new.update(_learner_push_fit(cfg, state, train, step, _gat))
+    with jax.named_scope("learner"):
+        new.update(_learner_push_fit(cfg, state, train, step, _gat))
     new.update(t=t + cfg.dt, step=step + 1, ws=ws, win=win, bl=bl)
     out = dict(
         fin=_gat(m["srv_fin"]), uid=_gat(m["srv_uid"]),
@@ -1927,27 +1946,33 @@ def serve_tick(cfg, state, n_arr, uid_base, feat=None, labels=None):
     ``(n_shards, max_arrivals_per_tick)`` int array of known labels for
     this tick's injections, aligned with the uid order; NaN feature rows
     and -1 labels mean "simulate from the embedding bank". Both must be
-    None for Gaussian features."""
+    None for Gaussian features.
+
+    The ``serve.dispatch`` span (``repro.obs.timing``) covers argument
+    conversion and the call up to its return, which is on dispatch: the
+    device work and the fetch of ``out`` come after."""
     cfg = _as_serve_config(cfg)
-    n_arr = jnp.asarray(n_arr, jnp.int32)
-    uid_base = jnp.asarray(uid_base, jnp.int32)
-    if cfg.learner.feature_kind == "lm":
-        S, M = cfg.n_shards, cfg.max_arrivals_per_tick
-        F = cfg.learner.n_features
-        feat = jnp.full((S, M, F), jnp.nan, jnp.float32) if feat is None \
-            else jnp.asarray(feat, jnp.float32)
-        labels = jnp.full((S, M), -1, jnp.int32) if labels is None \
-            else jnp.asarray(labels, jnp.int32)
-        if feat.shape != (S, M, F) or labels.shape != (S, M):
+    with timing.span("serve.dispatch"):
+        n_arr = jnp.asarray(n_arr, jnp.int32)
+        uid_base = jnp.asarray(uid_base, jnp.int32)
+        if cfg.learner.feature_kind == "lm":
+            S, M = cfg.n_shards, cfg.max_arrivals_per_tick
+            F = cfg.learner.n_features
+            feat = jnp.full((S, M, F), jnp.nan, jnp.float32) if feat is None \
+                else jnp.asarray(feat, jnp.float32)
+            labels = jnp.full((S, M), -1, jnp.int32) if labels is None \
+                else jnp.asarray(labels, jnp.int32)
+            if feat.shape != (S, M, F) or labels.shape != (S, M):
+                raise ValueError(
+                    f"serve_tick lm injections must be feat ({S}, {M}, {F}) "
+                    f"and labels ({S}, {M}); got {feat.shape} / "
+                    f"{labels.shape}")
+        elif feat is not None or labels is not None:
             raise ValueError(
-                f"serve_tick lm injections must be feat ({S}, {M}, {F}) "
-                f"and labels ({S}, {M}); got {feat.shape} / {labels.shape}")
-    elif feat is not None or labels is not None:
-        raise ValueError(
-            "serve_tick feat/labels injections require learner."
-            "feature_kind='lm' (Gaussian tasks draw identity in the tick)")
-    if cfg.sharding.n_devices > 1:
-        return _serve_tick_sharded_jit(cfg)(state, n_arr, uid_base,
-                                            feat, labels)
-    return _serve_tick_jit(cfg, state, n_arr, uid_base, feat, labels,
-                           _bank_for(cfg))
+                "serve_tick feat/labels injections require learner."
+                "feature_kind='lm' (Gaussian tasks draw identity in the tick)")
+        if cfg.sharding.n_devices > 1:
+            return _serve_tick_sharded_jit(cfg)(state, n_arr, uid_base,
+                                                feat, labels)
+        return _serve_tick_jit(cfg, state, n_arr, uid_base, feat, labels,
+                               _bank_for(cfg))

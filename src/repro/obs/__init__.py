@@ -6,8 +6,9 @@ The pieces:
   * ``trace``  — :class:`TraceConfig`, the engine-native trace switch the
     jitted paths read (router/simfast), and :class:`EventsTrace`, the
     host-side recorder the scalar event loop fills;
-  * ``timing`` — process-wide wall-clock registry (cold = compile+execute
-    vs warm = execute per jitted entry point);
+  * ``timing`` — process-wide wall-clock registry: the program's spans
+    (``timing.span``, also written to a profiler trace) and cold =
+    compile+execute vs warm = execute per jitted entry point;
   * ``export`` — versioned JSON-lines trace artifacts written next to the
     ``BENCH_*.json`` files (``python -m repro.obs.export <scenario>``);
   * ``report`` — text dashboard over any trace artifact

@@ -18,6 +18,14 @@ conservation ``submitted == answered + pending + in_system + dropped (+
 shutdown)`` holds at every tick boundary (tests/test_serving.py pins it
 under concurrent clients).
 
+The loop's work is timed by ``repro.obs.timing`` spans, so a profiler
+trace shows it beside the device's: ``serve.inject`` and ``serve.absorb``
+(the host side of a tick), ``serve.tick`` (the device call and the fetch
+of its answers; its child ``serve.dispatch`` in ``serve_tick`` returns on
+dispatch, so the rest is the fetch), ``serve.embed`` (LM scenarios),
+``serve.yield`` (the event loop's other work, mostly HTTP, before the
+next tick) and ``serve.idle`` (nothing pending).
+
 Endpoints (JSON in/out):
 
   ``POST /tasks``          submit one task; body ``{"wait": bool,
@@ -32,7 +40,10 @@ Endpoints (JSON in/out):
                            class for accuracy accounting).
   ``GET /labels/<id>``     current state of a submission.
   ``GET /stats``           counters, conservation check, wall-clock
-                           latency percentiles, ``repro.obs.timing`` rows.
+                           latency percentiles (of the most recent
+                           ``LATENCY_WINDOW`` answers), running sums of
+                           each answer's queue wait and ticks to answer,
+                           ``repro.obs.timing`` rows.
   ``GET /healthz``         liveness.
   ``POST /shutdown``       graceful shutdown: stop accepting, drain.
 """
@@ -47,6 +58,11 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs import timing
+
+# answers whose latency /stats percentiles cover (the most recent ones)
+LATENCY_WINDOW = 1 << 16
+
 _REASON = {200: "OK", 202: "Accepted", 400: "Bad Request", 404: "Not Found",
            429: "Too Many Requests", 503: "Service Unavailable"}
 
@@ -54,7 +70,9 @@ _REASON = {200: "OK", 202: "Accepted", 400: "Bad Request", 404: "Not Found",
 @dataclasses.dataclass
 class _Req:
     """One submission's lifecycle. ``status`` walks pending (host queue)
-    -> queued (on device) -> done | dropped | shutdown."""
+    -> queued (on device) -> done | dropped | shutdown. ``t_inject`` and
+    ``tick_inject`` mark its injection (the server's clock and tick
+    count), ``tick_answer`` the tick that answered it."""
     rid: int
     event: asyncio.Event
     t_submit: float
@@ -68,6 +86,9 @@ class _Req:
     votes: int = 0
     tis_s: float = 0.0
     t_answer: Optional[float] = None
+    t_inject: Optional[float] = None
+    tick_inject: int = -1
+    tick_answer: int = -1
 
     def to_json(self) -> dict:
         d = dict(id=self.rid, status=self.status)
@@ -137,7 +158,11 @@ class LabelServer:
         self.ticks = 0
         self.t_sim = 0.0
         self._in_flight = 0
-        self._lat: list = []
+        self._lat = collections.deque(maxlen=LATENCY_WINDOW)
+        # running sums over every answer: queue wait (submit to inject)
+        # and ticks from injection to answer, counting both ends
+        self.queue_wait_s_sum = 0.0
+        self.answer_ticks_sum = 0
         self._work: Optional[asyncio.Event] = None
         self._drained: Optional[asyncio.Event] = None
         self._closing = False
@@ -229,6 +254,7 @@ class LabelServer:
         n_arr = np.zeros((S,), np.int32)
         room = np.minimum(M, Q - self._backlog)
         inject = []                   # (shard, slot, req) needing embed
+        now = time.monotonic()
         while self._pending:
             s = int(np.argmax(room - n_arr))
             if room[s] - n_arr[s] <= 0:
@@ -237,6 +263,7 @@ class LabelServer:
             req.shard = s
             req.uid = int(self._next_uid[s]) + int(n_arr[s])
             req.status = "queued"
+            req.t_inject, req.tick_inject = now, self.ticks
             self._by_uid[(s, req.uid)] = req
             if self._lm and (req.text is not None or req.given_label >= 0):
                 inject.append((s, int(n_arr[s]), req))
@@ -247,27 +274,23 @@ class LabelServer:
 
     def _device_tick(self, n_arr, uid_base, inject=()):
         """Blocking jitted tick + transfer of the small srv_* bundle
-        (runs on the executor thread; wall-clock lands in the
+        (runs on the executor thread; the ``serve.tick`` span lands in the
         ``repro.obs.timing`` registry, so the first call's compile shows
         up as the cold-vs-warm split). LM scenarios batch-embed any
         text-carrying submissions here (one encoder call per tick) and
         inject the vectors + known labels into this tick's arrivals."""
         import jax
         from repro.labelstream.router import serve_tick
-        from repro.obs import timing
 
         feat = labels = None
         if self._lm and inject:
             feat, labels = self._embed_plan(n_arr, inject)
 
-        def step():
+        with timing.span("serve.tick"):
             self.state, out = serve_tick(self.cfg, self.state, n_arr,
                                          uid_base, feat=feat,
                                          labels=labels)
             return jax.device_get(out)
-
-        out, _ = timing.timeit("serve.tick", step)
-        return out
 
     def _embed_plan(self, n_arr, inject):
         """Turn the tick's text-carrying submissions into the router's
@@ -277,7 +300,6 @@ class LabelServer:
         (:func:`repro.embed.bank.embed_texts`) in the bank's
         standardized feature space."""
         from repro.embed.bank import embed_texts
-        from repro.obs import timing
 
         cfg = self.cfg
         S, M = cfg.n_shards, cfg.max_arrivals_per_tick
@@ -286,10 +308,11 @@ class LabelServer:
         labels = np.full((S, M), -1, np.int32)
         texted = [(s, w, r) for s, w, r in inject if r.text is not None]
         if texted:
-            vecs, _ = timing.timeit("serve.embed", lambda: np.asarray(
-                embed_texts(cfg.learner.embed, [r.text for _, _, r in texted],
-                            cfg.n_classes, F, cfg.learner.class_sep,
-                            cfg.learner.hard_sep_scale)))
+            with timing.span("serve.embed"):
+                vecs = np.asarray(embed_texts(
+                    cfg.learner.embed, [r.text for _, _, r in texted],
+                    cfg.n_classes, F, cfg.learner.class_sep,
+                    cfg.learner.hard_sep_scale))
             for (s, w, _), v in zip(texted, vecs):
                 feat[s, w] = v
         for s, w, r in inject:
@@ -315,8 +338,11 @@ class LabelServer:
             req.conf = float(confs[s, w])
             req.tis_s = float(tis[s, w])
             req.t_answer = now
+            req.tick_answer = self.ticks
             self.answered += 1
             self._lat.append(now - req.t_submit)
+            self.queue_wait_s_sum += req.t_inject - req.t_submit
+            self.answer_ticks_sum += self.ticks - req.tick_inject + 1
             req.event.set()
         drp = np.asarray(out["dropped"])
         if drp.any():
@@ -342,17 +368,21 @@ class LabelServer:
                 if self._closing:
                     self._drained.set()
                 self._work.clear()
-                await self._work.wait()
+                with timing.span("serve.idle"):
+                    await self._work.wait()
             t0 = time.monotonic()
-            n_arr, uid_base, inject = self._inject_plan()
+            with timing.span("serve.inject"):
+                n_arr, uid_base, inject = self._inject_plan()
             out = await loop.run_in_executor(
                 None, self._device_tick, n_arr, uid_base, inject)
-            self._absorb(out, n_arr, uid_base)
+            with timing.span("serve.absorb"):
+                self._absorb(out, n_arr, uid_base)
             if self._closing and not self._pending and not self._by_uid:
                 self._drained.set()
             lag = self.tick_interval_s - (time.monotonic() - t0)
             # always yield so request handlers interleave with the loop
-            await asyncio.sleep(lag if lag > 0 else 0)
+            with timing.span("serve.yield"):
+                await asyncio.sleep(lag if lag > 0 else 0)
 
     # ------------------------------------------------------------------
     # HTTP surface
@@ -478,8 +508,6 @@ class LabelServer:
     # introspection
     # ------------------------------------------------------------------
     def stats(self) -> dict:
-        from repro.obs import timing
-
         lat = np.asarray(self._lat) if self._lat else np.zeros((0,))
         in_system = len(self._by_uid)
         s = dict(
@@ -493,6 +521,8 @@ class LabelServer:
                           + self.shutdown_unanswered),
             p50_latency_s=float(np.percentile(lat, 50)) if lat.size else None,
             p95_latency_s=float(np.percentile(lat, 95)) if lat.size else None,
+            queue_wait_s_sum=self.queue_wait_s_sum,
+            answer_ticks_sum=self.answer_ticks_sum,
             timing=[row for row in timing.summary()
                     if row["name"] in ("serve.tick", "serve.embed")],
         )

@@ -544,3 +544,65 @@ def test_offline_ds_refresh_keeps_quality():
     out2 = run_stream(cfg, HORIZON, n_reps=2, seed=7)
     np.testing.assert_array_equal(np.asarray(out["hist"]),
                                   np.asarray(out2["hist"]))
+
+
+# ------------------------------------------- serve tick phase named scopes --
+
+# every phase of the serve tick runs in this scenario: cross-shard stealing,
+# the learner, and the offline EM refresh every other tick
+SCOPED_SERVE = ("stream_sharded", {"policy.learner.enabled": True,
+                                   "policy.learner.refresh_every": 2})
+TICK_SCOPES = ("admission", "votes", "refresh", "fusion", "finalize",
+               "credit", "bookkeeping", "maintenance", "assign", "steal",
+               "learner")
+
+
+@pytest.fixture(scope="module")
+def scoped_serve_cfg():
+    from repro.scenarios import get_scenario
+    from repro.scenarios.compile import to_serve_config
+    return to_serve_config(get_scenario(*SCOPED_SERVE))
+
+
+def test_serve_tick_lowering_names_every_phase_scope(scoped_serve_cfg):
+    """Each phase of the tick carries its ``jax.named_scope`` into the
+    ops' location metadata (``vmap(<scope>)`` inside the per-shard vmap),
+    which is what a device trace and the compiled HLO's ``op_name`` name
+    the phase by."""
+    import re
+
+    from repro.labelstream.router import _serve_tick_jit, serve_init
+
+    cfg = scoped_serve_cfg
+    arr = np.zeros((cfg.n_shards,), np.int32)
+    text = _serve_tick_jit.lower(cfg, serve_init(cfg, 0), arr, arr, None,
+                                 None, None).as_text(debug_info=True)
+    missing = [s for s in TICK_SCOPES
+               if not re.search(rf"[/(]{s}[)/]", text)]
+    assert not missing, missing
+
+
+def test_scoped_serve_tick_outputs_match_pinned_run(scoped_serve_cfg):
+    """Named scopes change metadata only: 40 ticks of the scenario give
+    the outputs and end state pinned from the program before the scopes
+    were added."""
+    import hashlib
+
+    from repro.labelstream.router import serve_init, serve_tick
+
+    cfg = scoped_serve_cfg
+    S = cfg.n_shards
+    state = serve_init(cfg, seed=0)
+    h, base, fin = hashlib.sha256(), np.zeros((S,), np.int64), 0
+    for i in range(40):
+        n = np.asarray([(i + s) % 3 for s in range(S)], np.int32)
+        state, out = serve_tick(cfg, state, n, base.astype(np.int32))
+        base += n
+        out = jax.device_get(out)
+        fin += int(out["fin"].sum())
+        for k in sorted(out):
+            h.update(np.ascontiguousarray(out[k]).tobytes())
+    for x in jax.tree_util.tree_leaves(jax.device_get(state)):
+        h.update(np.ascontiguousarray(x).tobytes())
+    assert fin == 44
+    assert h.hexdigest()[:16] == "112d90ffa79db94d"

@@ -298,3 +298,149 @@ def test_timing_registry_cold_warm_split():
     assert s["g"]["warm_s"] is None and s["g"]["compile_s"] is None
     timing.clear()
     assert timing.summary() == []
+
+
+def test_span_records_start_end_parent_and_self_time():
+    import time
+
+    from repro.obs import timing
+    timing.clear()
+    t_before = time.monotonic()
+    with timing.span("outer"):
+        with timing.span("inner"):
+            time.sleep(0.02)
+        with timing.span("inner"):
+            time.sleep(0.01)
+        time.sleep(0.01)
+    t_after = time.monotonic()
+    recs = timing.spans()
+    assert [r.name for r in recs] == ["inner", "inner", "outer"]
+    outer = timing.spans("outer")[0]
+    inners = timing.spans("inner")
+    assert outer.parent is None
+    assert all(r.parent == outer.id for r in inners)
+    assert t_before <= outer.start <= inners[0].start <= inners[0].end \
+        <= inners[1].start <= inners[1].end <= outer.end <= t_after
+    own = timing.self_time(outer, recs)
+    assert own == pytest.approx(outer.seconds - inners[0].seconds
+                                - inners[1].seconds)
+    assert 0.01 <= own < outer.seconds
+    assert timing.self_time(inners[0], recs) == inners[0].seconds
+    # the per-name duration lists the registry always kept
+    ent = timing.entries()
+    assert ent["outer"] == [outer.seconds]
+    assert ent["inner"] == [r.seconds for r in inners]
+    timing.clear()
+    assert timing.spans() == [] and timing.entries() == {}
+
+
+def test_span_parent_follows_the_thread():
+    """A span open on one thread is no parent to spans another thread
+    opens meanwhile; spans that close out of order (asyncio tasks that
+    interleave on one thread) leave no stale parent behind."""
+    import threading
+
+    from repro.obs import timing
+    timing.clear()
+    opened, done = threading.Event(), threading.Event()
+
+    def holder():
+        with timing.span("held"):
+            opened.set()
+            done.wait(10)
+            with timing.span("held.child"):
+                pass
+
+    th = threading.Thread(target=holder)
+    th.start()
+    opened.wait(10)
+    with timing.span("other"):
+        pass
+    done.set()
+    th.join(10)
+    assert not th.is_alive()
+    by = {r.name: r for r in timing.spans()}
+    assert by["other"].parent is None
+    assert by["held.child"].parent == by["held"].id
+    assert by["held"].start < by["other"].start < by["held"].end
+
+    a, b = timing.span("a"), timing.span("b")
+    a.__enter__(), b.__enter__()
+    a.__exit__(None, None, None), b.__exit__(None, None, None)
+    with timing.span("after"):
+        pass
+    assert timing.spans("after")[0].parent is None
+    timing.clear()
+
+
+def test_timeit_fills_entries_as_before():
+    from repro.obs import timing
+    timing.clear()
+    out, dt = timing.timeit("f", lambda a, b=0: a + b, 2, b=3)
+    timing.timeit("f", lambda: None)
+    assert out == 5 and dt >= 0.0
+    ent = timing.entries()
+    assert list(ent) == ["f"] and len(ent["f"]) == 2 and ent["f"][0] == dt
+    assert [s.name for s in timing.spans()] == ["f", "f"]
+    assert {e["name"]: e["calls"] for e in timing.summary()} == {"f": 2}
+    timing.clear()
+
+
+def test_span_annotates_only_while_a_profiler_records(monkeypatch):
+    """No annotation object is made while nothing records: making one per
+    span slowed the served tick on the chip."""
+    from repro.obs import timing
+
+    made = []
+
+    class Annotation:
+        enabled = False
+
+        @classmethod
+        def is_enabled(cls):
+            return cls.enabled
+
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(timing, "_ANNOTATION", Annotation)
+    with timing.span("off"):
+        pass
+    Annotation.enabled = True
+    with timing.span("on"):
+        pass
+    assert made == ["on"]
+    assert [s.name for s in timing.spans()][-2:] == ["off", "on"]
+    timing.clear()
+
+
+def test_span_lands_on_the_host_plane_of_a_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from repro.obs import timing
+    timing.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with timing.span("obs.test.span"):
+            jnp.ones((8,)).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    hits = [(pl.name, ev.duration_ns) for pl in pd.planes
+            if pl.name.startswith("/host:") for ln in pl.lines
+            for ev in ln.events if ev.name == "obs.test.span"]
+    assert len(hits) == 1, hits
+    sp = timing.spans("obs.test.span")[0]
+    # the annotation opens before the registry's first clock read and
+    # closes after its last
+    assert sp.seconds <= hits[0][1] * 1e-9 <= sp.seconds + 1e-3
+    timing.clear()

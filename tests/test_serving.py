@@ -62,6 +62,78 @@ def test_conservation_under_concurrent_clients():
         assert r["latency_s"] >= 0.0
 
 
+def _serve_waited(n_clients, per_client):
+    """Serve ``n_clients`` racing keep-alive clients, each making
+    ``per_client`` waited submissions; returns the closed server and its
+    final stats."""
+    from repro.serving.server import ServeClient
+
+    async def main():
+        srv = await _server().start()
+
+        async def client():
+            c = await ServeClient(srv.host, srv.port).connect()
+            for _ in range(per_client):
+                status, r = await c.submit(wait=True, timeout_s=60.0)
+                assert status == 200 and r["status"] == "done", (status, r)
+            await c.aclose()
+
+        await asyncio.gather(*[client() for _ in range(n_clients)])
+        stats = srv.stats()
+        await srv.close()
+        return srv, stats
+
+    return asyncio.run(main())
+
+
+def test_request_counters_and_tick_spans():
+    """Each answered request carries its injection time and the ticks at
+    injection and answer; ``/stats`` sums them; every ``serve.tick`` span
+    holds exactly one ``serve.dispatch`` (the router's call)."""
+    from repro.obs import timing
+
+    timing.clear()
+    srv, stats = _serve_waited(4, 6)
+    reqs = list(srv._reqs.values())
+    assert len(reqs) == stats["answered"] == 24
+    for r in reqs:
+        assert r.status == "done"
+        assert r.t_submit <= r.t_inject <= r.t_answer
+        assert 0 <= r.tick_inject <= r.tick_answer < srv.ticks
+    assert stats["queue_wait_s_sum"] == pytest.approx(
+        sum(r.t_inject - r.t_submit for r in reqs))
+    assert stats["answer_ticks_sum"] == sum(
+        r.tick_answer - r.tick_inject + 1 for r in reqs)
+
+    ticks = timing.spans("serve.tick")
+    dispatch = timing.spans("serve.dispatch")
+    assert len(ticks) == srv.ticks == len(dispatch)
+    for t in ticks:
+        kids = [d for d in dispatch if d.parent == t.id]
+        assert len(kids) == 1
+        assert t.start <= kids[0].start <= kids[0].end <= t.end
+    for name in ("serve.inject", "serve.absorb"):
+        assert len(timing.spans(name)) == srv.ticks, name
+    timing.clear()
+
+
+def test_latency_percentiles_cover_the_most_recent_answers(monkeypatch):
+    """``/stats`` percentiles read a bounded window of the latest
+    answers, so a long-running server's latency store stops growing."""
+    from repro.serving import server as server_mod
+
+    monkeypatch.setattr(server_mod, "LATENCY_WINDOW", 4)
+    srv, stats = _serve_waited(2, 5)
+    assert stats["answered"] == 10
+    assert len(srv._lat) == 4
+    reqs = sorted(srv._reqs.values(), key=lambda r: r.t_answer)
+    latest = {r.t_answer - r.t_submit for r in reqs
+              if r.t_answer >= reqs[-4].t_answer}
+    assert set(srv._lat) <= latest
+    assert stats["p50_latency_s"] == pytest.approx(
+        float(np.percentile(list(srv._lat), 50)))
+
+
 def test_request_timeout_keeps_task_in_system():
     """A wait=True submission whose long-poll times out gets 202 — but
     only the HTTP wait dies; the task stays in the system, finalizes on
