@@ -55,8 +55,10 @@ window most-uncertain-first under the current model.
 """
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -841,8 +843,9 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
     if cfg.serve:
         # per-slot finalization outputs for the live serving front end:
         # which slots finalized this tick, their request uids, fused-label
-        # answers and posterior confidence — the ONLY arrays that leave the
-        # device each tick (the router state itself stays resident)
+        # answers and posterior confidence — with the per-shard counts, the
+        # ONLY data that leaves the device each tick, packed into one buffer
+        # by the serve tick (the router state itself stays resident)
         metrics["srv_fin"] = fin
         metrics["srv_uid"] = win["uid"]
         metrics["srv_label"] = result.astype(jnp.int32)
@@ -1746,11 +1749,74 @@ def stream_summary(cfg, out) -> dict:
 # HTTP submissions are micro-batched into per-shard injected arrival
 # counts (``StreamConfig.serve`` replaces the sampled arrival process with
 # exact counts and threads a request uid through backlog ring, window slot
-# and steal transfers), the donated device state never round-trips to host
-# between ticks, and the only arrays leaving the device per tick are the
-# small ``srv_*`` finalization outputs.
+# and steal transfers), and the donated device state never round-trips to
+# host between ticks. One buffer crosses each way per tick: the injection
+# counts and uid bases go up as one ``(2, n_shards)`` int32 array, and the
+# small ``srv_*`` finalization outputs with the per-shard occupancy come
+# back packed into one int32 buffer (:class:`TickOut`), so the launch
+# allocates one output buffer and the host makes one transfer in place of
+# twelve.
 
 _SERVE_SHARDED_KEYS = ("ws", "banks", "win", "bl", "seeds")
+
+
+class TickOut(collections.abc.Mapping):
+    """``serve_tick``'s output bundle: a read-only mapping over ONE packed
+    int32 buffer, which is its only pytree leaf (``jax.device_get`` moves
+    it in one transfer). ``layout`` holds ``(key, shape, dtype, offset)``
+    per field, in sorted key order; float32 fields travel bit-cast and
+    ``fin`` as 0/1. ``out[k]`` gives the field as a host numpy array of
+    its own shape and dtype (``fin`` bool, ``conf``/``tis`` float32, ``t``
+    a float32 scalar), fetching and caching the buffer on first use."""
+
+    __slots__ = ("buf", "layout", "_fields")
+
+    def __init__(self, buf, layout):
+        self.buf, self.layout, self._fields = buf, layout, None
+
+    def _unpack(self) -> dict:
+        if self._fields is None:
+            host = np.asarray(self.buf)
+            fields = {}
+            for k, shape, dtype, off in self.layout:
+                a = host[off:off + math.prod(shape)]
+                a = (a != 0) if dtype == "bool" else a.view(dtype)
+                a = a.reshape(shape)
+                a.flags.writeable = False
+                fields[k] = a
+            self._fields = fields
+        return self._fields
+
+    def __getitem__(self, k):
+        return self._unpack()[k]
+
+    def __iter__(self):
+        return (k for k, *_ in self.layout)
+
+    def __len__(self):
+        return len(self.layout)
+
+
+jax.tree_util.register_pytree_node(
+    TickOut, lambda o: ((o.buf,), o.layout),
+    lambda layout, leaves: TickOut(leaves[0], layout))
+
+
+def _pack_tick_out(out: dict) -> TickOut:
+    """Pack the tick's ``out`` bundle into one int32 buffer (traced: the
+    layout follows from the fields' shapes, so it is fixed once per
+    compiled StreamConfig), fields in sorted key order as a returned dict
+    would flatten. Bit-exact: bool becomes 0/1, the 4-byte fields (int32,
+    float32) are bit-cast."""
+    layout, words, off = [], [], 0
+    for k in sorted(out):
+        x = out[k]
+        w = x.astype(jnp.int32) if x.dtype == jnp.bool_ \
+            else jax.lax.bitcast_convert_type(x, jnp.int32)
+        layout.append((k, tuple(x.shape), np.dtype(x.dtype).name, off))
+        words.append(w.reshape(-1))
+        off += math.prod(x.shape)
+    return TickOut(jnp.concatenate(words), tuple(layout))
 
 
 def _as_serve_config(cfg) -> StreamConfig:
@@ -1868,10 +1934,12 @@ def _serve_tick_impl(cfg: StreamConfig, state, n_arr, uid_base,
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-def _serve_tick_jit(cfg: StreamConfig, state, n_arr, uid_base, feat_in,
-                    labels_in, bank):
-    return _serve_tick_impl(cfg, state, n_arr, uid_base, feat_in=feat_in,
-                            labels_in=labels_in, bank=bank)
+def _serve_tick_jit(cfg: StreamConfig, state, inj, feat_in, labels_in,
+                    bank):
+    """``inj`` stacks the tick's ``n_arr`` over its ``uid_base``."""
+    new, out = _serve_tick_impl(cfg, state, inj[0], inj[1], feat_in=feat_in,
+                                labels_in=labels_in, bank=bank)
+    return new, _pack_tick_out(out)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1879,8 +1947,8 @@ def _serve_tick_sharded_jit(cfg: StreamConfig):
     """Compiled shard_map-partitioned serve tick for
     ``cfg.sharding.n_devices`` (same mesh plumbing as ``_run_sharded_jit``:
     per-shard state subtrees live sharded over the "shard" axis, the
-    gathered ``srv_*`` outputs come out replicated, and the state buffers
-    are donated tick over tick)."""
+    packed buffer of the gathered ``srv_*`` outputs comes out replicated,
+    and the state buffers are donated tick over tick)."""
     from jax.sharding import PartitionSpec as Pspec
 
     from repro.launch.mesh import check_stream_sharding, make_stream_mesh
@@ -1891,12 +1959,12 @@ def _serve_tick_sharded_jit(cfg: StreamConfig):
     # the lm bank is a per-config constant closed over (replicated), same
     # as _run_sharded_jit; None on the gaussian path
     bank = _bank_for(cfg)
-    lm = cfg.learner.feature_kind == "lm"
 
-    def body(state, n_arr, uid_base, feat_in, labels_in):
-        return _serve_tick_impl(cfg, state, n_arr, uid_base,
-                                feat_in=feat_in, labels_in=labels_in,
-                                bank=bank, axis_name="shard")
+    def body(state, inj, feat_in, labels_in):
+        new, out = _serve_tick_impl(cfg, state, inj[0], inj[1],
+                                    feat_in=feat_in, labels_in=labels_in,
+                                    bank=bank, axis_name="shard")
+        return new, _pack_tick_out(out)
 
     state_shapes = jax.eval_shape(functools.partial(serve_init, cfg, 0))
     state_specs = {
@@ -1904,21 +1972,10 @@ def _serve_tick_sharded_jit(cfg: StreamConfig):
             lambda _: Pspec("shard") if k in _SERVE_SHARDED_KEYS
             else Pspec(), v)
         for k, v in state_shapes.items()}
-    arr_sh = jax.ShapeDtypeStruct((cfg.n_shards,), jnp.int32)
-    M, F = cfg.max_arrivals_per_tick, cfg.learner.n_features
-    feat_sh = jax.ShapeDtypeStruct((cfg.n_shards, M, F), jnp.float32) \
-        if lm else None
-    lab_sh = jax.ShapeDtypeStruct((cfg.n_shards, M), jnp.int32) \
-        if lm else None
-    out_shapes = jax.eval_shape(
-        lambda s, na, ub: _serve_tick_impl(cfg, s, na, ub, feat_in=feat_sh,
-                                           labels_in=lab_sh, bank=bank),
-        state_shapes, arr_sh, arr_sh)
-    rep_specs = jax.tree_util.tree_map(lambda _: Pspec(), out_shapes[1])
     fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(state_specs, Pspec("shard"), Pspec("shard"),
+                       in_specs=(state_specs, Pspec(None, "shard"),
                                  Pspec("shard"), Pspec("shard")),
-                       out_specs=(state_specs, rep_specs), check_vma=False)
+                       out_specs=(state_specs, Pspec()), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -1937,8 +1994,16 @@ def serve_tick(cfg, state, n_arr, uid_base, feat=None, labels=None):
     the window slots finalized this tick and ``uid``/``label``/``votes``/
     ``conf``/``tis`` give their request uid, fused label, vote count,
     posterior confidence and time-in-system (leading dim n_shards), plus
-    per-shard ``backlog``/``in_flight``/``stolen``/``donated`` occupancy
-    and the post-tick clock ``t``.
+    per-shard ``dropped``/``backlog``/``in_flight``/``stolen``/``donated``
+    counts and the post-tick clock ``t``.
+
+    ``out`` is a :class:`TickOut`: a read-only mapping of those twelve
+    keys over one packed int32 device buffer, its only pytree leaf, so
+    ``jax.device_get(out)`` is one transfer. ``out[k]`` is a host numpy
+    array of the field's own shape and dtype (``fin`` bool), bit-exact
+    with what the tick computed; read without ``device_get``, the first
+    access fetches the buffer. ``n_arr`` and ``uid_base`` go up as one
+    ``(2, n_shards)`` int32 array.
 
     In lm mode (``learner.feature_kind="lm"``), ``feat`` is an optional
     ``(n_shards, max_arrivals_per_tick, n_features)`` float array of
@@ -1953,8 +2018,9 @@ def serve_tick(cfg, state, n_arr, uid_base, feat=None, labels=None):
     device work and the fetch of ``out`` come after."""
     cfg = _as_serve_config(cfg)
     with timing.span("serve.dispatch"):
-        n_arr = jnp.asarray(n_arr, jnp.int32)
-        uid_base = jnp.asarray(uid_base, jnp.int32)
+        # a host array: jit's own transfer moves it, in one upload
+        inj = np.stack([np.asarray(n_arr, np.int32),
+                        np.asarray(uid_base, np.int32)])
         if cfg.learner.feature_kind == "lm":
             S, M = cfg.n_shards, cfg.max_arrivals_per_tick
             F = cfg.learner.n_features
@@ -1972,7 +2038,6 @@ def serve_tick(cfg, state, n_arr, uid_base, feat=None, labels=None):
                 "serve_tick feat/labels injections require learner."
                 "feature_kind='lm' (Gaussian tasks draw identity in the tick)")
         if cfg.sharding.n_devices > 1:
-            return _serve_tick_sharded_jit(cfg)(state, n_arr, uid_base,
-                                                feat, labels)
-        return _serve_tick_jit(cfg, state, n_arr, uid_base, feat, labels,
+            return _serve_tick_sharded_jit(cfg)(state, inj, feat, labels)
+        return _serve_tick_jit(cfg, state, inj, feat, labels,
                                _bank_for(cfg))

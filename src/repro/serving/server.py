@@ -12,11 +12,12 @@ timestamps.
 The router state is a donated device pytree (`serve_tick` aliases input
 to output buffers), so window/backlog/pool arrays never round-trip to
 host between ticks; the only per-tick host transfer is the small
-``srv_*`` finalization bundle. Injection is throttled to each shard's
-free backlog capacity, so the device never drops a request on its own —
-conservation ``submitted == answered + pending + in_system + dropped (+
-shutdown)`` holds at every tick boundary (tests/test_serving.py pins it
-under concurrent clients).
+``srv_*`` finalization bundle, packed into one buffer (``stats()``
+counts the buffers fetched in ``tick_out_buffers_sum``: one per tick).
+Injection is throttled to each shard's free backlog capacity, so the
+device never drops a request on its own — conservation ``submitted ==
+answered + pending + in_system + dropped (+ shutdown)`` holds at every
+tick boundary (tests/test_serving.py pins it under concurrent clients).
 
 The loop's work is timed by ``repro.obs.timing`` spans, so a profiler
 trace shows it beside the device's: ``serve.inject`` and ``serve.absorb``
@@ -163,6 +164,8 @@ class LabelServer:
         # and ticks from injection to answer, counting both ends
         self.queue_wait_s_sum = 0.0
         self.answer_ticks_sum = 0
+        # device buffers the tick fetches bring back, summed over ticks
+        self.tick_out_buffers_sum = 0
         self._work: Optional[asyncio.Event] = None
         self._drained: Optional[asyncio.Event] = None
         self._closing = False
@@ -273,12 +276,13 @@ class LabelServer:
         return n_arr, uid_base, inject
 
     def _device_tick(self, n_arr, uid_base, inject=()):
-        """Blocking jitted tick + transfer of the small srv_* bundle
-        (runs on the executor thread; the ``serve.tick`` span lands in the
-        ``repro.obs.timing`` registry, so the first call's compile shows
-        up as the cold-vs-warm split). LM scenarios batch-embed any
-        text-carrying submissions here (one encoder call per tick) and
-        inject the vectors + known labels into this tick's arrivals."""
+        """Blocking jitted tick + transfer of the small srv_* bundle, one
+        packed buffer (runs on the executor thread; the ``serve.tick``
+        span lands in the ``repro.obs.timing`` registry, so the first
+        call's compile shows up as the cold-vs-warm split). LM scenarios
+        batch-embed any text-carrying submissions here (one encoder call
+        per tick) and inject the vectors + known labels into this tick's
+        arrivals."""
         import jax
         from repro.labelstream.router import serve_tick
 
@@ -290,7 +294,9 @@ class LabelServer:
             self.state, out = serve_tick(self.cfg, self.state, n_arr,
                                          uid_base, feat=feat,
                                          labels=labels)
-            return jax.device_get(out)
+            host = jax.device_get(out)
+        self.tick_out_buffers_sum += len(jax.tree_util.tree_leaves(out))
+        return host
 
     def _embed_plan(self, n_arr, inject):
         """Turn the tick's text-carrying submissions into the router's
@@ -523,6 +529,7 @@ class LabelServer:
             p95_latency_s=float(np.percentile(lat, 95)) if lat.size else None,
             queue_wait_s_sum=self.queue_wait_s_sum,
             answer_ticks_sum=self.answer_ticks_sum,
+            tick_out_buffers_sum=self.tick_out_buffers_sum,
             timing=[row for row in timing.summary()
                     if row["name"] in ("serve.tick", "serve.embed")],
         )

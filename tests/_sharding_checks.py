@@ -33,6 +33,48 @@ def _common(out_a, out_b):
     return ({k: out_a[k] for k in keys}, {k: out_b[k] for k in keys})
 
 
+def _serve_outs(cfg, n_ticks: int):
+    """``n_ticks`` serve ticks under a fixed injection schedule: each
+    tick's fetched output and the pytree leaf count of its device output."""
+    import jax
+    import numpy as np
+
+    from repro.labelstream.router import serve_init, serve_tick
+
+    S = cfg.n_shards
+    state, base = serve_init(cfg, seed=3), np.zeros((S,), np.int32)
+    outs, n_leaves = [], set()
+    for i in range(n_ticks):
+        n = np.asarray([(i + s) % 3 for s in range(S)], np.int32)
+        state, out = serve_tick(cfg, state, n, base)
+        base = base + n
+        n_leaves.add(len(jax.tree_util.tree_leaves(out)))
+        outs.append(jax.device_get(out))
+    return outs, n_leaves
+
+
+def _serve_packed_parity(scenario: str, D: int) -> dict:
+    """The sharded serve tick on ``D`` devices against one device: the
+    packed output unpacks to the same keys, shapes, dtypes and bytes."""
+    import numpy as np
+
+    from repro import scenarios
+    from repro.scenarios.compile import to_serve_config
+
+    o1, l1 = _serve_outs(to_serve_config(
+        scenarios.get_scenario(scenario)), 12)
+    oD, lD = _serve_outs(to_serve_config(scenarios.get_scenario(
+        scenario, {"sharding.n_devices": D})), 12)
+    same = all(
+        list(a) == list(b) and all(
+            a[k].shape == b[k].shape and a[k].dtype == b[k].dtype
+            and a[k].tobytes() == b[k].tobytes() for k in a)
+        for a, b in zip(o1, oD))
+    return dict(parity=same, one_leaf=l1 == lD == {1},
+                fin_bool=all(o["fin"].dtype == np.bool_ for o in oD),
+                finalized=sum(int(o["fin"].sum()) for o in oD))
+
+
 def collect() -> dict:
     import jax
     import jax.numpy as jnp
@@ -199,6 +241,12 @@ def collect() -> dict:
     lD = run_stream(to_stream_config(lmD), HORIZON, n_reps=N_REPS, seed=3)
     a, b = _common(l1, lD)
     report["lm_parity_sharded"] = _tree_equal(a, b)
+
+    # ---- sharded serve tick: one packed buffer out, bitwise the single-
+    # device tick, on the Gaussian and the LM path (2 devices) ----------
+    for name in ("stream_sharded", "lm_stream"):
+        report["serve_packed_" + name] = _serve_packed_parity(
+            name, min(2, D))
 
     # ---- chip_smoke.py's four-chip phase, rehearsed on host devices ----
     import importlib.util

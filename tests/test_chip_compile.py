@@ -136,18 +136,17 @@ def _serve_cfg(name, overrides):
 def _tick_args(cfg, sharding):
     from repro.labelstream.router import serve_init
     state = jax.eval_shape(functools.partial(serve_init, cfg, 0))
-    arr = jax.ShapeDtypeStruct((cfg.n_shards,), jnp.int32,
+    inj = jax.ShapeDtypeStruct((2, cfg.n_shards), jnp.int32,
                                sharding=sharding)
-    return _sds(state, sharding), arr
+    return _sds(state, sharding), inj
 
 
 def test_serve_tick_compiles_at_deployment_size(one_chip):
     from repro.labelstream.router import _serve_tick_jit
     sm = _smoke()
     cfg = _serve_cfg(sm.SERVE_SCENARIO, sm.SERVE_OVERRIDES)
-    state, arr = _tick_args(cfg, one_chip)
-    c = _serve_tick_jit.lower(cfg, state, arr, arr, None, None,
-                              None).compile()
+    state, inj = _tick_args(cfg, one_chip)
+    c = _serve_tick_jit.lower(cfg, state, inj, None, None, None).compile()
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
@@ -163,9 +162,8 @@ def test_serve_tick_refresh_selects_the_kernel_on_tpu(one_chip,
     monkeypatch.setattr(aggregate, "estep_mode", lambda: (True, False))
     cfg = _serve_cfg(sm.SERVE_SCENARIO, dict(
         sm.SERVE_OVERRIDES, **{"policy.learner.refresh_every": 8}))
-    state, arr = _tick_args(cfg, one_chip)
-    c = _serve_tick_jit.lower(cfg, state, arr, arr, None, None,
-                              None).compile()
+    state, inj = _tick_args(cfg, one_chip)
+    c = _serve_tick_jit.lower(cfg, state, inj, None, None, None).compile()
     assert _mosaic(c)
 
 
@@ -192,8 +190,10 @@ def test_sharded_serve_tick_compiles_on_four_chips(four_chip_mesh):
     rep = NamedSharding(four_chip_mesh, PartitionSpec())
     state = {k: _sds(v, shard if k in router._SERVE_SHARDED_KEYS else rep)
              for k, v in state.items()}
-    arr = jax.ShapeDtypeStruct((cfg.n_shards,), jnp.int32, sharding=shard)
-    c = fn.lower(state, arr, arr, None, None).compile()
+    inj = jax.ShapeDtypeStruct(
+        (2, cfg.n_shards), jnp.int32,
+        sharding=NamedSharding(four_chip_mesh, PartitionSpec(None, "shard")))
+    c = fn.lower(state, inj, None, None).compile()
     assert "all-gather" in c.as_text()
 
 

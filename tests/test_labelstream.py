@@ -574,9 +574,9 @@ def test_serve_tick_lowering_names_every_phase_scope(scoped_serve_cfg):
     from repro.labelstream.router import _serve_tick_jit, serve_init
 
     cfg = scoped_serve_cfg
-    arr = np.zeros((cfg.n_shards,), np.int32)
-    text = _serve_tick_jit.lower(cfg, serve_init(cfg, 0), arr, arr, None,
-                                 None, None).as_text(debug_info=True)
+    inj = np.zeros((2, cfg.n_shards), np.int32)
+    text = _serve_tick_jit.lower(cfg, serve_init(cfg, 0), inj, None, None,
+                                 None).as_text(debug_info=True)
     missing = [s for s in TICK_SCOPES
                if not re.search(rf"[/(]{s}[)/]", text)]
     assert not missing, missing

@@ -117,6 +117,13 @@ def test_request_counters_and_tick_spans():
     timing.clear()
 
 
+def test_tick_fetch_moves_one_buffer_per_tick():
+    """The served tick fetches one packed device buffer per tick."""
+    srv, stats = _serve_waited(3, 4)
+    assert stats["ticks"] > 0
+    assert stats["tick_out_buffers_sum"] == stats["ticks"]
+
+
 def test_latency_percentiles_cover_the_most_recent_answers(monkeypatch):
     """``/stats`` percentiles read a bounded window of the latest
     answers, so a long-running server's latency store stops growing."""
@@ -356,6 +363,72 @@ def test_serve_tick_deterministic_fixed_seed():
     # the finalization stream actually finalized something
     total_fin = sum(int(np.asarray(o["fin"]).sum()) for o in outs_a)
     assert total_fin > 0
+
+
+@pytest.mark.parametrize("name", ["serve_default", "lm_stream"])
+def test_packed_tick_out_matches_unpacked_fields(name):
+    """``serve_tick``'s packed output unpacks, key by key, to the fields
+    the tick computes before packing: same shape, dtype and bytes, with
+    one pytree leaf, whether read after ``device_get`` or directly."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from repro import scenarios
+    from repro.labelstream.router import (
+        TickOut, _bank_for, _serve_tick_impl, serve_init, serve_tick,
+    )
+
+    cfg = scenarios.to_serve_config(scenarios.get_scenario(name))
+    S = cfg.n_shards
+    kw = {}
+    if cfg.learner.feature_kind == "lm":
+        # serve_tick's defaults: every injection draws from the bank
+        M, F = cfg.max_arrivals_per_tick, cfg.learner.n_features
+        kw = dict(feat_in=jnp.full((S, M, F), jnp.nan, jnp.float32),
+                  labels_in=jnp.full((S, M), -1, jnp.int32),
+                  bank=_bank_for(cfg))
+    unpacked = jax.jit(functools.partial(_serve_tick_impl, cfg))
+    state, ref_state = serve_init(cfg, seed=0), serve_init(cfg, seed=0)
+    base, fin = np.zeros((S,), np.int32), 0
+    for i in range(24):
+        n = np.asarray([(i + s) % 3 for s in range(S)], np.int32)
+        ref_state, ref = unpacked(ref_state, n, base, **kw)
+        state, out = serve_tick(cfg, state, n, base)
+        base = base + n
+        assert isinstance(out, TickOut)
+        assert len(jax.tree_util.tree_leaves(out)) == 1
+        direct = {k: out[k] for k in out}
+        host, ref = jax.device_get(out), jax.device_get(ref)
+        assert list(host) == sorted(ref)
+        for k, r in ref.items():
+            r = np.asarray(r)
+            for got in (host[k], direct[k]):
+                assert (got.shape, got.dtype) == (r.shape, r.dtype), k
+                assert got.tobytes() == r.tobytes(), k
+        assert host["fin"].dtype == np.bool_
+        fin += int(host["fin"].sum())
+    assert fin > 0
+
+
+def test_packed_tick_out_keeps_nonfinite_floats_bit_exact():
+    """NaN (with its payload), infinities and -0.0 survive the bit-cast
+    packing, next to bool and int32 fields and a scalar."""
+    import jax
+    from repro.labelstream.router import _pack_tick_out
+
+    conf = np.array([0.0, np.inf, -np.inf, -0.0, 1.5], np.float32)
+    conf[0] = np.frombuffer(np.uint32(0x7FC00123).tobytes(), np.float32)[0]
+    fields = dict(fin=np.array([[True, False], [False, True]]), conf=conf,
+                  uid=np.array([-2, 2**31 - 1], np.int32),
+                  t=np.float32(np.nan))
+    out = jax.device_get(jax.jit(_pack_tick_out)(fields))
+    assert list(out) == sorted(fields)
+    assert len(jax.tree_util.tree_leaves(out)) == 1
+    for k, x in fields.items():
+        x = np.asarray(x)
+        assert (out[k].shape, out[k].dtype) == (x.shape, x.dtype), k
+        assert out[k].tobytes() == x.tobytes(), k
 
 
 def test_tick_loop_failure_stops_server_and_launcher(monkeypatch):

@@ -114,6 +114,16 @@ def test_embedding_bank_sharded_gather_parity(report):
     assert report["lm_parity_sharded"] is True
 
 
+@pytest.mark.parametrize("name", ["stream_sharded", "lm_stream"])
+def test_sharded_serve_tick_packed_output(report, name):
+    """The sharded serve tick returns one packed, replicated buffer that
+    unpacks bitwise to the single-device tick's fields, ``fin`` bool."""
+    r = report["serve_packed_" + name]
+    assert r["parity"] is True
+    assert r["one_leaf"] is True
+    assert r["fin_bool"] is True
+
+
 def test_chip_smoke_four_chip_phase(report):
     """chip_smoke.py --four-chips' comparison (4 devices against 1, every
     output digest equal, conservation) passes on host devices."""
