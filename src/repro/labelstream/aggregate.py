@@ -8,11 +8,15 @@ module is the vectorized replacement and the engine behind
   * votes live in dense padded arrays — ``labels``/``workers`` (T, V) int32
     with a validity ``mask`` — produced by :func:`pack_votes`;
   * the E-step is one fused gather+softmax over a log-confusion row table
-    (row ``w*C + l`` holds ``log P(vote=l | true=c)`` for worker w), either
-    as pure jnp or through the Pallas kernel ``kernels/ds_estep.py``
-    (interpret mode on CPU, Mosaic on TPU);
-  * the M-step is a padded scatter-add of posteriors into (worker, label)
-    bins — the same segment-sum idiom as simfast's vote accumulation;
+    (a row per (worker w, label l) pair some vote names, holding
+    ``log P(vote=l | true=c)``), either as pure jnp or through the Pallas
+    kernel ``kernels/ds_estep.py`` (interpret mode on CPU, Mosaic on TPU);
+  * the M-step is a padded scatter-add of posteriors into those
+    (worker, label) rows and into per-worker totals — the same segment-sum
+    idiom as simfast's vote accumulation. The rows stay in that layout
+    across iterations, and at most one per vote exists, so an iteration
+    moves O(T V C) bytes however many workers and classes there are; the
+    whole (W, C, C) confusion is built once, after the last iteration;
   * EM iterations run under ``lax.scan``; independent replications vmap
     through :func:`dawid_skene_batch`.
 
@@ -23,7 +27,10 @@ Two observation models:
     assert exact agreement;
   * ``one_coin=False`` — full C x C confusion matrix per worker with
     Laplace-smoothed rows, which additionally captures class-dependent
-    error (a worker who always answers 0 stops dragging class-0 tasks).
+    error (a worker who always answers 0 stops dragging class-0 tasks);
+    a worker's accuracy is the smoothed share of its posterior-weighted
+    votes on the diagonal, so it stays meaningful when C is large and a
+    worker has seen few of the classes.
 """
 from __future__ import annotations
 
@@ -85,12 +92,11 @@ def pack_votes(task_votes, *, pad_tasks_to: Optional[int] = None,
     return pack, n_workers
 
 
-def _row_table(log_conf, n_workers, n_classes):
-    """(W, C_true, C_vote) log-confusion -> (W*C+1, C_true) row table with a
-    trailing all-zero null row for masked votes."""
-    rows = log_conf.transpose(0, 2, 1).reshape(n_workers * n_classes,
-                                               n_classes)
-    return jnp.concatenate([rows, jnp.zeros((1, n_classes), rows.dtype)])
+def _row_table(log_conf_rows, n_classes):
+    """(K, C_true) log-confusion rows -> the E-step's row table: a
+    trailing all-zero null row for masked votes appended (index K)."""
+    return jnp.concatenate([log_conf_rows,
+                            jnp.zeros((1, n_classes), log_conf_rows.dtype)])
 
 
 def estep_mode(use_kernel: Optional[bool] = None) -> "tuple[bool, bool]":
@@ -103,8 +109,8 @@ def estep_mode(use_kernel: Optional[bool] = None) -> "tuple[bool, bool]":
     return (on_tpu if use_kernel is None else bool(use_kernel)), not on_tpu
 
 
-def _estep(log_conf, idx, n_workers, n_classes, use_kernel, interpret):
-    rows = _row_table(log_conf, n_workers, n_classes)
+def _estep(log_conf_rows, idx, n_classes, use_kernel, interpret):
+    rows = _row_table(log_conf_rows, n_classes)
     if use_kernel:
         from repro.kernels.ds_estep import ds_estep
         logp, post = ds_estep(rows, idx, interpret=interpret)
@@ -118,46 +124,75 @@ def _ds_em(labels, workers, mask, n_workers, n_classes, iters, one_coin,
            use_kernel, interpret):
     T, V = labels.shape
     W, C = n_workers, n_classes
-    R = W * C
-    # masked votes point at the null row; real votes at row w*C + label
-    idx = jnp.where(mask, workers * C + labels, R).astype(jnp.int32)
-    flat_idx = idx.reshape(-1)
-    votes_per_worker = (jnp.zeros((W + 1,))
-                        .at[jnp.where(mask, workers, W)].add(1.0))[:W]
+    R, N = W * C, T * V
+    # vote j's confusion row is (worker, label) pair w*C + l; only the
+    # pairs some vote names are ever read, so the rows are kept for those
+    # alone: K = N slots, slot k holding pair ``pair[k]`` (the masked
+    # votes' pair R and the unused slots are never read)
+    valid = mask.reshape(-1)
+    key = jnp.where(mask, workers * C + labels, R).reshape(-1)
+    pair, slot = jnp.unique(key, size=N, fill_value=R, return_inverse=True)
+    slot = slot.reshape(-1)
+    pair_w, pair_l = pair // C, pair % C            # pair R -> worker W
+    # the E-step's row index: the vote's slot, masked votes the null row
+    idx = jnp.where(valid, slot, N).reshape(T, V).astype(jnp.int32)
+    w_v = jnp.where(valid, workers.reshape(-1), W)
+    l_v = labels.reshape(-1)
+    t_v = jnp.repeat(jnp.arange(T), V)
+    votes_per_worker = jnp.zeros((W + 1,)).at[w_v].add(1.0)[:W]
     maskf = mask.astype(jnp.float32)
+    cls = jnp.arange(C)
 
-    def conf_from_acc(acc):
-        a = jnp.clip(acc, ACC_CLIP, 1.0 - ACC_CLIP)
+    def conf_from_acc(acc, w, l):
+        """Rows (w, l) of the one-coin confusion at accuracies ``acc``."""
+        a = jnp.clip(acc, ACC_CLIP, 1.0 - ACC_CLIP)[w]
         off = (1.0 - a) / max(C - 1, 1)
-        eye = jnp.eye(C, dtype=jnp.float32)
-        return (a[:, None, None] * eye
-                + off[:, None, None] * (1.0 - eye))      # (W, C, C)
+        return jnp.where(cls[None, :] == l[:, None], a[:, None],
+                         off[:, None])
 
     def mstep(post):
-        # post[t, c] scattered into (worker, vote-label) bins: one padded
-        # segment-sum, no (T, V, W) one-hot
-        contrib = jnp.broadcast_to(post[:, None, :], (T, V, C)) \
-            * maskf[:, :, None]
-        counts = (jnp.zeros((R + 1, C))
-                  .at[flat_idx].add(contrib.reshape(T * V, C)))[:R]
-        counts = counts.reshape(W, C, C).transpose(0, 2, 1)  # (W, true, vote)
+        # each vote's posterior into its pair's row and its worker's
+        # totals: padded segment-sums over the N votes, no (W, C, C) table
+        post_v = post[t_v] * valid[:, None]
+        diag = jnp.zeros((W + 1,)).at[w_v].add(
+            jnp.take_along_axis(post_v, l_v[:, None], 1)[:, 0])[:W]
         if one_coin:
             # Beta(1,1)-smoothed symmetric accuracy — identical to the
             # scalar reference's num/den update
-            diag = jnp.einsum("wcc->w", counts)
             acc = (1.0 + diag) / (2.0 + jnp.maximum(votes_per_worker, 0.0))
-            return conf_from_acc(acc), acc
-        row_tot = counts.sum(-1, keepdims=True)
-        conf = (counts + 1.0 / C) / (row_tot + 1.0)      # Laplace rows
-        acc = jnp.einsum("wcc->w", conf) / C
+            return conf_from_acc(acc, jnp.minimum(pair_w, W - 1), pair_l), \
+                acc
+        counts = jnp.zeros((N, C)).at[slot].add(post_v)      # (pair, true)
+        row_tot = jnp.zeros((W + 1, C)).at[w_v].add(post_v)  # (W+1, true)
+        conf = (counts + 1.0 / C) / (row_tot[pair_w] + 1.0)   # Laplace rows
+        # the share of the worker's posterior-weighted votes the EM
+        # explains as correct, with the rows' smoothing (one pseudo-vote
+        # spread over the C labels); the mean of the confusion's diagonal
+        # would weigh the classes the worker never saw as uniform rows
+        acc = (diag + 1.0 / C) / (row_tot[:W].sum(-1) + 1.0)
         return conf, acc
 
-    conf0 = conf_from_acc(jnp.full((W,), INIT_ACC))
+    def dense_confusion(post, acc):
+        """The last M-step's whole (W, true, vote) confusion, pairs no vote
+        names included (computed once, for callers that read it)."""
+        eye = jnp.eye(C, dtype=jnp.float32)
+        if one_coin:
+            a = jnp.clip(acc, ACC_CLIP, 1.0 - ACC_CLIP)[:, None, None]
+            return a * eye + (1.0 - a) / max(C - 1, 1) * (1.0 - eye)
+        counts = (jnp.zeros((R + 1, C)).at[key].add(post[t_v]
+                                                    * valid[:, None]))
+        counts = counts[:R].reshape(W, C, C)              # (W, vote, true)
+        row_tot = counts.sum(1)
+        return ((counts + 1.0 / C) / (row_tot[:, None, :] + 1.0)) \
+            .transpose(0, 2, 1)
+
+    conf0 = conf_from_acc(jnp.full((W,), INIT_ACC),
+                          jnp.minimum(pair_w, W - 1), pair_l)
 
     def body(carry, _):
         conf, _acc, _logp, _post = carry
         logp, post = _estep(jnp.log(jnp.clip(conf, CONF_CLIP, 1.0)), idx,
-                            W, C, use_kernel, interpret)
+                            C, use_kernel, interpret)
         conf, acc = mstep(post)
         # the E-step output rides in the carry (not the stacked ys), so
         # only the last iteration's O(T*C) posterior is materialized
@@ -169,7 +204,7 @@ def _ds_em(labels, workers, mask, n_workers, n_classes, iters, one_coin,
     # scalar reference order: labels come from the E-step of the LAST
     # iteration, accuracies from the M-step that follows it
     return dict(log_posterior=logp, posterior=post,
-                confusion=conf, accuracy=acc,
+                confusion=dense_confusion(post, acc), accuracy=acc,
                 n_votes=maskf.sum(-1), votes_per_worker=votes_per_worker)
 
 

@@ -23,7 +23,10 @@ The loop's work is timed by ``repro.obs.timing`` spans, so a profiler
 trace shows it beside the device's: ``serve.inject`` and ``serve.absorb``
 (the host side of a tick), ``serve.tick`` (the device call and the fetch
 of its answers; its child ``serve.dispatch`` in ``serve_tick`` returns on
-dispatch, so the rest is the fetch), ``serve.embed`` (LM scenarios),
+dispatch, so the rest is the fetch), ``serve.refresh_tick`` (around
+``serve.tick`` on the ticks whose step runs the offline Dawid-Skene
+refresh, every ``refresh_every``-th; ``stats()`` counts them in
+``refresh_ticks_sum``), ``serve.embed`` (LM scenarios),
 ``serve.yield`` (the event loop's other work, mostly HTTP, before the
 next tick) and ``serve.idle`` (nothing pending).
 
@@ -52,6 +55,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import contextlib
 import dataclasses
 import json
 import time
@@ -166,6 +170,8 @@ class LabelServer:
         self.answer_ticks_sum = 0
         # device buffers the tick fetches bring back, summed over ticks
         self.tick_out_buffers_sum = 0
+        # ticks whose step ran the offline Dawid-Skene refresh
+        self.refresh_ticks_sum = 0
         self._work: Optional[asyncio.Event] = None
         self._drained: Optional[asyncio.Event] = None
         self._closing = False
@@ -290,12 +296,16 @@ class LabelServer:
         if self._lm and inject:
             feat, labels = self._embed_plan(n_arr, inject)
 
-        with timing.span("serve.tick"):
+        every = self.cfg.refresh_every
+        refresh = every > 0 and self.ticks % every == every - 1
+        with timing.span("serve.refresh_tick") if refresh \
+                else contextlib.nullcontext(), timing.span("serve.tick"):
             self.state, out = serve_tick(self.cfg, self.state, n_arr,
                                          uid_base, feat=feat,
                                          labels=labels)
             host = jax.device_get(out)
         self.tick_out_buffers_sum += len(jax.tree_util.tree_leaves(out))
+        self.refresh_ticks_sum += refresh
         return host
 
     def _embed_plan(self, n_arr, inject):
@@ -530,6 +540,7 @@ class LabelServer:
             queue_wait_s_sum=self.queue_wait_s_sum,
             answer_ticks_sum=self.answer_ticks_sum,
             tick_out_buffers_sum=self.tick_out_buffers_sum,
+            refresh_ticks_sum=self.refresh_ticks_sum,
             timing=[row for row in timing.summary()
                     if row["name"] in ("serve.tick", "serve.embed")],
         )

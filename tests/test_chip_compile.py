@@ -90,6 +90,7 @@ def test_ds_estep_compiles(one_chip, T, V, W, C):
 @pytest.mark.parametrize("B,T,V,W,C", [
     (4, 4096, 5, 64, 10),    # dawid_skene_batch in the chip smoke
     (8, 256, 3, 129, 2),     # the serve tick's refresh: 8 shards x window
+    (8, 256, 3, 129, 200),   # the same at cub200's 200 classes
 ])
 def test_ds_estep_vmapped_compiles(one_chip, B, T, V, W, C):
     from repro.kernels.ds_estep import ds_estep
@@ -165,6 +166,26 @@ def test_serve_tick_refresh_selects_the_kernel_on_tpu(one_chip,
     state, inj = _tick_args(cfg, one_chip)
     c = _serve_tick_jit.lower(cfg, state, inj, None, None, None).compile()
     assert _mosaic(c)
+
+
+def test_serve_tick_compiles_for_cub200(one_chip, monkeypatch):
+    """The 200-class deployment of ``chipbench/configs/cub200.json``, its
+    refresh on: the E-step is the Mosaic kernel and the tick's arguments
+    and temporaries fit one chip's memory."""
+    import json
+
+    from repro.labelstream import aggregate
+    from repro.labelstream.router import _serve_tick_jit
+    cub = json.loads((_SMOKE.parent / "chipbench" / "configs"
+                      / "cub200.json").read_text())
+    monkeypatch.setattr(aggregate, "estep_mode", lambda: (True, False))
+    cfg = _serve_cfg(cub["scenario"], cub["overrides"])
+    assert cfg.n_classes == 200 and cfg.refresh_every > 0
+    state, inj = _tick_args(cfg, one_chip)
+    c = _serve_tick_jit.lower(cfg, state, inj, None, None, None).compile()
+    assert _mosaic(c)
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 N_CHIPS = 4

@@ -133,6 +133,88 @@ def test_ds_estep_kernel_matches_ref():
     np.testing.assert_allclose(np.asarray(post)[7], 0.25, atol=1e-6)
 
 
+@pytest.mark.parametrize("W,C,T,V", [(9, 200, 77, 3), (9, 4, 300, 5),
+                                     (3, 2, 32, 5), (129, 200, 40, 3)])
+def test_ds_estep_kernel_gathers_rows_at_any_width(W, C, T, V):
+    """The row-gathering E-step against its oracle at 200 classes and at
+    the widths above, one table and a batch of tables under ``vmap``
+    (padded, null and repeated votes included)."""
+    from repro.kernels import ref
+    from repro.kernels.ds_estep import ds_estep
+    rng = np.random.default_rng(W * C + T)
+    R = W * C + 1
+    rows = np.log(rng.uniform(0.05, 0.95, (3, R, C))).astype(np.float32)
+    rows[:, -1] = 0.0
+    idx = rng.integers(0, R, (3, T, V)).astype(np.int32)
+    idx[:, 5] = R - 1                      # zero-vote task
+    idx[:, 6] = idx[:, 6, :1]              # one row named by every vote
+    logp, post = ds_estep(jnp.array(rows[0]), jnp.array(idx[0]),
+                          interpret=True)
+    logp_r, post_r = ref.ds_estep_ref(jnp.array(rows[0]),
+                                      jnp.array(idx[0]))
+    np.testing.assert_allclose(np.asarray(logp), np.asarray(logp_r),
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(post), np.asarray(post_r),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(post)[5], 1.0 / C, atol=1e-6)
+    bl, bp = jax.vmap(lambda r, i: ds_estep(r, i, interpret=True))(
+        jnp.array(rows), jnp.array(idx))
+    rl, rp = jax.vmap(ref.ds_estep_ref)(jnp.array(rows), jnp.array(idx))
+    np.testing.assert_allclose(np.asarray(bl), np.asarray(rl), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(bp), np.asarray(rp), atol=1e-5)
+
+
+def _one_coin_votes(n_tasks, n_workers, n_classes, acc, votes, seed):
+    """Each task voted by ``votes`` distinct workers of a one-coin crowd
+    of accuracy ``acc``; wrong votes uniform over the other classes."""
+    rng = np.random.default_rng(seed)
+    truth = rng.integers(0, n_classes, n_tasks)
+    labels = np.zeros((n_tasks, votes), np.int32)
+    workers = np.zeros((n_tasks, votes), np.int32)
+    for t in range(n_tasks):
+        workers[t] = rng.choice(n_workers, votes, replace=False)
+        for j in range(votes):
+            if rng.random() < acc:
+                labels[t, j] = truth[t]
+            else:
+                w = int(rng.integers(0, n_classes - 1))
+                labels[t, j] = w + 1 if w >= truth[t] else w
+    return labels, workers, np.ones((n_tasks, votes), bool), truth
+
+
+def test_full_confusion_accuracy_at_200_classes():
+    """A one-coin crowd of accuracy 0.9 over 200 classes: the full-
+    confusion EM's accuracies come out near 0.9 and near each worker's
+    share of right votes, not near the mean of its confusion's diagonal,
+    whose rows of unseen classes stay uniform at 1/C."""
+    labels, workers, mask, truth = _one_coin_votes(600, 20, 200, 0.9, 3,
+                                                   seed=1)
+    out = dawid_skene(labels, workers, mask, n_workers=20, n_classes=200,
+                      iters=8)
+    acc = np.asarray(out["accuracy"])
+    right = labels == truth[:, None]
+    # each worker's share of right votes, with the EM's smoothing
+    seen = np.array([(right[workers == w].sum() + 1 / 200)
+                     / ((workers == w).sum() + 1) for w in range(20)])
+    assert abs(acc.mean() - 0.9) < 0.03, acc
+    np.testing.assert_allclose(acc, seen, atol=0.03)
+    conf = np.asarray(out["confusion"])
+    # each worker saw under half of the classes: the diagonal's mean
+    # would read below 0.5
+    assert np.einsum("wcc->w", conf).mean() / 200 < 0.5
+
+
+def test_full_confusion_accuracy_at_two_classes_matches_diagonal_mean():
+    """At two balanced classes the diagonal share of a worker's votes
+    stays within 0.02 of the mean of its confusion's diagonal."""
+    labels, workers, mask, _ = _one_coin_votes(800, 10, 2, 0.85, 3, seed=2)
+    out = dawid_skene(labels, workers, mask, n_workers=10, n_classes=2,
+                      iters=8)
+    diag_mean = np.einsum("wcc->w", np.asarray(out["confusion"])) / 2
+    np.testing.assert_allclose(np.asarray(out["accuracy"]), diag_mean,
+                               atol=0.02)
+
+
 def test_ds_em_with_kernel_estep_matches_jnp_path():
     tv, _ = _synthetic_votes(n_tasks=20, seed=7)
     pack, n_workers = pack_votes(tv)
@@ -585,7 +667,11 @@ def test_serve_tick_lowering_names_every_phase_scope(scoped_serve_cfg):
 def test_scoped_serve_tick_outputs_match_pinned_run(scoped_serve_cfg):
     """Named scopes change metadata only: 40 ticks of the scenario give
     the outputs and end state pinned from the program before the scopes
-    were added."""
+    were added. Re-pinned when the refresh's full-confusion accuracy
+    became the diagonal share of the worker's votes (with the former
+    accuracy that program read the former pin, 112d90ffa79db94d) and the
+    M-step came to keep only the confusion rows the votes name: every
+    integer and mask the same as the dense M-step's, floats within 5e-7."""
     import hashlib
 
     from repro.labelstream.router import serve_init, serve_tick
@@ -605,4 +691,4 @@ def test_scoped_serve_tick_outputs_match_pinned_run(scoped_serve_cfg):
     for x in jax.tree_util.tree_leaves(jax.device_get(state)):
         h.update(np.ascontiguousarray(x).tobytes())
     assert fin == 44
-    assert h.hexdigest()[:16] == "112d90ffa79db94d"
+    assert h.hexdigest()[:16] == "038eeb20b612916a"

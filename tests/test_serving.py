@@ -124,6 +124,47 @@ def test_tick_fetch_moves_one_buffer_per_tick():
     assert stats["tick_out_buffers_sum"] == stats["ticks"]
 
 
+def test_refresh_ticks_have_their_span_and_counter():
+    """With the offline Dawid-Skene refresh every 3 ticks, each tick whose
+    step runs it (every third, counted from the first) sits inside a
+    ``serve.refresh_tick`` span and is counted in ``refresh_ticks_sum``;
+    with the refresh off there are none."""
+    from repro import scenarios
+    from repro.obs import timing
+    from repro.serving.server import LabelServer, ServeClient
+
+    async def main(every):
+        spec = scenarios.get_scenario(
+            "serve_default", {"policy.learner.refresh_every": every})
+        srv = await LabelServer(spec, seed=0, port=0,
+                                tick_interval_s=0.0).start()
+        c = await ServeClient(srv.host, srv.port).connect()
+        for _ in range(4):
+            status, r = await c.submit(wait=True, timeout_s=60.0)
+            assert status == 200 and r["status"] == "done", (status, r)
+        await c.aclose()
+        stats = srv.stats()
+        await srv.close()
+        return srv, stats
+
+    timing.clear()
+    srv, stats = asyncio.run(main(3))
+    assert stats["refresh_ticks_sum"] == srv.ticks // 3 > 0
+    ticks = timing.spans("serve.tick")
+    refresh = timing.spans("serve.refresh_tick")
+    assert len(refresh) == stats["refresh_ticks_sum"]
+    for i, t in enumerate(ticks):
+        inside = [r for r in refresh if t.parent == r.id]
+        assert len(inside) == (i % 3 == 2), i
+        for r in inside:
+            assert r.start <= t.start <= t.end <= r.end
+    timing.clear()
+    _, stats = asyncio.run(main(0))
+    assert stats["refresh_ticks_sum"] == 0
+    assert timing.spans("serve.refresh_tick") == []
+    timing.clear()
+
+
 def test_latency_percentiles_cover_the_most_recent_answers(monkeypatch):
     """``/stats`` percentiles read a bounded window of the latest
     answers, so a long-running server's latency store stops growing."""
