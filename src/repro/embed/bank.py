@@ -68,12 +68,23 @@ def embedding_bank(ec: EmbedConfig, n_classes: int, n_features: int,
     cfg = resolved_config(ec)
     tokens, lengths = make_tokens(ec, labels, hard, C, cfg.vocab_size,
                                   class_sep, hard_sep_scale)
-    E = encode(ec, tokens, lengths, n_features, shard=False)
+    E = jnp.asarray(encode(ec, tokens, lengths, n_features, shard=False))
     mu = E.mean(axis=0)
     sd = E.std(axis=0)
     X = standardize(E)
     return EmbeddingBank(feats=X.reshape(2, C, K, n_features),
                          mean=mu, std=sd)
+
+
+@functools.lru_cache(maxsize=None)
+def _bank_stats(ec: EmbedConfig, n_classes: int, n_features: int,
+                class_sep: float, hard_sep_scale: float):
+    """The bank's mean and floored std, fetched once, for standardizing
+    live embeddings on the host."""
+    bank = embedding_bank(ec, n_classes, n_features, class_sep,
+                          hard_sep_scale)
+    mean, std = jax.device_get((bank.mean, bank.std))
+    return mean, np.maximum(std, np.float32(1e-6))
 
 
 def bank_gather(feats, u, tl, diff):
@@ -94,15 +105,16 @@ def embed_texts(ec: EmbedConfig, texts, n_classes: int, n_features: int,
     (:func:`repro.embed.corpus.tokenize_text`), run the batched encoder,
     then normalize with the BANK's pre-standardization statistics — not
     the batch's own — so one-off live submissions land on the same scale
-    as the precomputed rows the learner was trained on. Returns an
-    ``(N, n_features)`` f32 array.
+    as the precomputed rows the learner was trained on (in numpy, from
+    the bank's statistics fetched once). Returns an ``(N, n_features)``
+    f32 numpy array.
 
     Spans (``repro.obs.timing``): ``embed.tokenize``, and ``embed.encode``
-    up to the encoder's return, which is on dispatch."""
+    up to the encoder's return, which fetches its features."""
     from repro.embed.corpus import tokenize_text
 
-    bank = embedding_bank(ec, n_classes, n_features, class_sep,
-                          hard_sep_scale)
+    mean, std = _bank_stats(ec, n_classes, n_features, class_sep,
+                            hard_sep_scale)
     cfg = resolved_config(ec)
     with timing.span("embed.tokenize"):
         pairs = [tokenize_text(t, ec.seq_len, cfg.vocab_size) for t in texts]
@@ -110,7 +122,7 @@ def embed_texts(ec: EmbedConfig, texts, n_classes: int, n_features: int,
         lengths = np.asarray([p[1] for p in pairs], np.int32)
     with timing.span("embed.encode"):
         E = encode(ec, tokens, lengths, n_features, shard=False)
-    return (E - bank.mean) / jnp.maximum(bank.std, 1e-6)
+    return (E - mean) / std
 
 
 def make_dataset(spec, n_train: int, n_test: int, seed: int = 0):

@@ -28,15 +28,17 @@ class EmbedConfig:
     optional redundant pin of that target width). ``bank_size`` is the
     number of precomputed task embeddings held device-resident by the
     :class:`~repro.embed.bank.EmbeddingBank`; ``batch_size`` is the
-    encoder micro-batch; ``seed`` fixes corpus tokens, model params and
-    the projection, so the whole feature path is deterministic."""
+    encoder's largest micro-batch (a shorter chunk runs in its
+    :func:`~repro.embed.encoder.bucket_rows`); ``seed`` fixes corpus
+    tokens, model params and the projection, so the whole feature path is
+    deterministic."""
     model: str = "xlstm-125m"
     reduced: bool = True
     pooling: str = "mean"         # "mean" | "last"
     seq_len: int = 48             # max tokens per task
     bank_size: int = 512          # precomputed embeddings (2*C*K layout)
     projection_dim: Optional[int] = None  # None = FeatureSpec.n_features
-    batch_size: int = 64          # encoder micro-batch
+    batch_size: int = 64          # encoder's largest micro-batch
     seed: int = 0
 
     def __post_init__(self):

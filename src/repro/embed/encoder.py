@@ -5,13 +5,18 @@ Token sequences run through ``models.model.forward`` with
 are pooled over the real (unpadded) positions — masked mean or the last
 real token — and projected to the learner's feature width by a seeded
 Gaussian random projection. Model params, the resolved config and the
-projection are all deterministic functions of :class:`EmbedConfig`, and
-every micro-batch is padded to the static ``batch_size`` by REPEATING
-the last row (the ``core.simfast._pad_keys`` idiom: real rows stay
-bit-identical whatever the batch remainder, pad rows are dropped), so a
-corpus embeds to the same features regardless of chunking or device
-count. With multiple visible devices the micro-batch axis is pmapped
-(pad -> reshape (D, B, T) -> pmap -> unpad).
+projection are all deterministic functions of :class:`EmbedConfig`.
+
+On one device a corpus runs in chunks of at most ``batch_size`` rows,
+one jitted call each. A chunk of ``n`` real rows is padded on the host to
+:func:`bucket_rows` — the next power of two, at least 8, at most
+``batch_size`` — by REPEATING the last row (the ``core.simfast._pad_keys``
+idiom: no op mixes the batch axis, so real rows come out the same whatever
+the padding, and pad rows are dropped). A few programs cover every chunk,
+and a tick's handful of texts runs a program sized to it rather than a
+full ``batch_size`` one. With multiple visible devices the micro-batch
+axis is pmapped instead (pad to ``batch_size * n_devices`` -> reshape
+(D, B, T) -> pmap -> unpad).
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.embed.config import EmbedConfig
 
@@ -91,26 +97,61 @@ _embed_pmap = jax.pmap(_embed_batch, static_broadcasted_argnums=(0, 4),
                        in_axes=(None, None, 0, 0, None, None))
 
 
+def bucket_rows(n: int, batch_size: int) -> int:
+    """Rows the encoder's program runs for a chunk of ``n`` real rows:
+    the next power of two, at least 8 (below that a matmul's rows and the
+    recurrence's (8, 128) tiles save nothing), at most ``batch_size``."""
+    return min(batch_size, max(8, 1 << (n - 1).bit_length()))
+
+
+def chunks(n_rows: int, batch_size: int) -> list:
+    """``(start, real rows, rows run)`` of each chunk :func:`encode`
+    makes of ``n_rows`` rows on one device."""
+    return [(i, min(batch_size, n_rows - i),
+             bucket_rows(min(batch_size, n_rows - i), batch_size))
+            for i in range(0, n_rows, batch_size)]
+
+
 def encode(ec: EmbedConfig, tokens, lengths, n_features: int, *,
            shard: bool = True):
     """Embed ``(N, seq_len)`` token sequences to ``(N, n_features)`` f32.
 
-    Chunked into static ``ec.batch_size`` micro-batches (one compilation
-    for any N); with ``shard`` and multiple visible devices each chunk
-    covers ``batch_size * n_devices`` rows and pmaps over them. Short
-    chunks are padded by repeating the last row and unpadded on the way
-    out, so results are independent of chunking and device count."""
+    On one device (``shard=False`` or a single visible device) the rows
+    run in the :func:`chunks` of ``ec.batch_size``: each chunk padded on
+    the host to its :func:`bucket_rows` by repeating its last row, one
+    jitted call per chunk, and one fetch for all of them; returns a numpy
+    array. With ``shard`` and multiple visible devices each chunk covers
+    ``batch_size * n_devices`` rows and pmaps over them. Either way the
+    result is independent of chunking and device count."""
     cfg = resolved_config(ec)
     params = model_params(ec)
     proj = projection(ec, n_features)
-    tokens = jnp.asarray(tokens, jnp.int32)
-    lengths = jnp.asarray(lengths, jnp.int32)
+    tokens = np.asarray(tokens, np.int32)
+    lengths = np.asarray(lengths, np.int32)
     if tokens.ndim != 2 or tokens.shape[1] != ec.seq_len:
         raise ValueError(f"tokens must be (N, seq_len={ec.seq_len}), "
                          f"got {tokens.shape}")
-    N, B = int(tokens.shape[0]), ec.batch_size
     D = jax.local_device_count() if shard else 1
-    step = B * D if D > 1 else B
+    if D > 1:
+        return _encode_pmap(ec, cfg, params, proj, tokens, lengths,
+                            n_features, D)
+    plan = chunks(int(tokens.shape[0]), ec.batch_size)
+    outs = []
+    for i, n, rows in plan:
+        idx = np.minimum(np.arange(i, i + rows), i + n - 1)
+        outs.append(_embed_jit(cfg, params, tokens[idx], lengths[idx],
+                               ec.pooling, proj))
+    outs = jax.device_get(outs)
+    return np.concatenate([o[:n] for o, (_, n, _) in zip(outs, plan)])
+
+
+def _encode_pmap(ec, cfg, params, proj, tokens, lengths, n_features, D):
+    """:func:`encode` over ``D`` devices: chunks of ``batch_size * D``
+    rows, padded to full size by repeating the last row."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    N, B = int(tokens.shape[0]), ec.batch_size
+    step = B * D
     feats = []
     for i in range(0, N, step):
         tb, lb = tokens[i:i + step], lengths[i:i + step]
@@ -120,11 +161,7 @@ def encode(ec: EmbedConfig, tokens, lengths, n_features: int, *,
             tb = jnp.concatenate(
                 [tb, jnp.broadcast_to(tb[-1:], (pad, tb.shape[1]))])
             lb = jnp.concatenate([lb, jnp.broadcast_to(lb[-1:], (pad,))])
-        if D > 1:
-            out = _embed_pmap(cfg, params, tb.reshape(D, B, -1),
-                              lb.reshape(D, B), ec.pooling, proj)
-            out = out.reshape(step, n_features)
-        else:
-            out = _embed_jit(cfg, params, tb, lb, ec.pooling, proj)
-        feats.append(out[:n])
+        out = _embed_pmap(cfg, params, tb.reshape(D, B, -1),
+                          lb.reshape(D, B), ec.pooling, proj)
+        feats.append(out.reshape(step, n_features)[:n])
     return jnp.concatenate(feats, axis=0)

@@ -14,6 +14,8 @@ to output buffers), so window/backlog/pool arrays never round-trip to
 host between ticks; the only per-tick host transfer is the small
 ``srv_*`` finalization bundle, packed into one buffer (``stats()``
 counts the buffers fetched in ``tick_out_buffers_sum``: one per tick).
+LM scenarios embed a tick's texts first; ``stats()`` counts them in
+``embed_rows_sum`` and the rows the encoder ran in ``embed_rows_run_sum``.
 Injection is throttled to each shard's free backlog capacity, so the
 device never drops a request on its own — conservation ``submitted ==
 answered + pending + in_system + dropped (+ shutdown)`` holds at every
@@ -172,6 +174,9 @@ class LabelServer:
         self.tick_out_buffers_sum = 0
         # ticks whose step ran the offline Dawid-Skene refresh
         self.refresh_ticks_sum = 0
+        # texts embedded, and the rows the encoder's programs ran for them
+        self.embed_rows_sum = 0
+        self.embed_rows_run_sum = 0
         self._work: Optional[asyncio.Event] = None
         self._drained: Optional[asyncio.Event] = None
         self._closing = False
@@ -316,6 +321,7 @@ class LabelServer:
         (:func:`repro.embed.bank.embed_texts`) in the bank's
         standardized feature space."""
         from repro.embed.bank import embed_texts
+        from repro.embed.encoder import chunks
 
         cfg = self.cfg
         S, M = cfg.n_shards, cfg.max_arrivals_per_tick
@@ -325,12 +331,16 @@ class LabelServer:
         texted = [(s, w, r) for s, w, r in inject if r.text is not None]
         if texted:
             with timing.span("serve.embed"):
-                vecs = np.asarray(embed_texts(
+                vecs = embed_texts(
                     cfg.learner.embed, [r.text for _, _, r in texted],
                     cfg.n_classes, F, cfg.learner.class_sep,
-                    cfg.learner.hard_sep_scale))
+                    cfg.learner.hard_sep_scale)
             for (s, w, _), v in zip(texted, vecs):
                 feat[s, w] = v
+            self.embed_rows_sum += len(texted)
+            self.embed_rows_run_sum += sum(
+                rows for _, _, rows in
+                chunks(len(texted), cfg.learner.embed.batch_size))
         for s, w, r in inject:
             if r.given_label >= 0:
                 labels[s, w] = r.given_label
@@ -541,6 +551,8 @@ class LabelServer:
             answer_ticks_sum=self.answer_ticks_sum,
             tick_out_buffers_sum=self.tick_out_buffers_sum,
             refresh_ticks_sum=self.refresh_ticks_sum,
+            embed_rows_sum=self.embed_rows_sum,
+            embed_rows_run_sum=self.embed_rows_run_sum,
             timing=[row for row in timing.summary()
                     if row["name"] in ("serve.tick", "serve.embed")],
         )

@@ -210,6 +210,39 @@ def test_encode_last_pooling_differs_from_mean():
     assert not np.array_equal(em, el)
 
 
+@pytest.mark.parametrize("N", [1, 5, 8, 9, 33, 64, 65, 100])
+def test_encode_bucketed_chunks_match_full_chunks(N, monkeypatch):
+    """Each chunk runs in a program of its bucket's rows (a power of two
+    in [8, batch_size], at least its real rows), one encoder call per
+    chunk, and its rows match the same texts run in full chunks."""
+    import repro.embed.encoder as encoder
+
+    cfg = resolved_config(EC)
+    B = EC.batch_size
+    rng = np.random.default_rng(N)
+    full = -(-N // B) * B
+    tokens = rng.integers(0, cfg.vocab_size, (full, EC.seq_len)).astype(
+        np.int32)
+    lengths = rng.integers(1, EC.seq_len + 1, full).astype(np.int32)
+    ref = np.asarray(encode(EC, tokens, lengths, 8, shard=False))[:N]
+    plan = encoder.chunks(N, B)
+    for _, n, rows in plan:
+        assert rows >= n and 8 <= rows <= B and rows & (rows - 1) == 0
+    real_jit = encoder._embed_jit
+    shapes = []
+
+    def counted(cfg_, params, tb, lb, pooling, proj):
+        shapes.append(tb.shape[0])
+        return real_jit(cfg_, params, tb, lb, pooling, proj)
+
+    monkeypatch.setattr(encoder, "_embed_jit", counted)
+    got = encode(EC, tokens[:N], lengths[:N], 8, shard=False)
+    assert got.shape == (N, 8) and got.dtype == np.float32
+    assert shapes == [rows for _, _, rows in plan]
+    assert len(shapes) == -(-N // B)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=0)
+
+
 def test_hidden_logits_mode_returns_final_norm_states():
     from repro.embed.encoder import model_params
     from repro.models.model import forward

@@ -344,6 +344,33 @@ def test_lm_text_submission_embeds_and_answers():
     assert "serve.embed" in timed, timed
 
 
+def test_lm_embed_row_counters_in_stats():
+    """``/stats`` counts the texts the server embedded and the rows the
+    encoder's programs ran for them: one text a tick (each waited on)
+    runs the smallest bucket."""
+    from repro import scenarios
+    from repro.embed.encoder import bucket_rows
+    from repro.serving.server import LabelServer, ServeClient
+
+    texts = ["the quick brown fox", "a lazy dog", "jumps over"]
+
+    async def main():
+        spec = scenarios.get_scenario("lm_stream")
+        srv = await LabelServer(spec, seed=0, port=0,
+                                tick_interval_s=0.0).start()
+        c = await ServeClient(srv.host, srv.port).connect()
+        for i, t in enumerate(texts):
+            await c.submit(wait=True, timeout_s=60.0, text=t, label=i % 2)
+        stats = await c.stats()
+        await c.aclose()
+        await srv.close()
+        return spec.embed.batch_size, stats
+
+    B, stats = asyncio.run(main())
+    assert stats["embed_rows_sum"] == len(texts)
+    assert stats["embed_rows_run_sum"] == len(texts) * bucket_rows(1, B)
+
+
 def test_text_submission_rejected_on_gaussian_scenario():
     """serve_default draws Gaussian features in the tick — there is no
     encoder to route text through, so text/label bodies are a 400 that
