@@ -162,33 +162,6 @@ def make_constrain(mesh, rules=None):
     return cons
 
 
-# ------------------------------------------------ stream shard groups ----
-
-
-def leading_axis_specs(tree, mesh_axis: str = "shard", axis: int = 0):
-    """PartitionSpec tree sharding each leaf's ``axis`` dim over ``mesh_axis``.
-
-    The labelstream shard-grouped state keeps pool shards on one array
-    dimension (leading for raw per-shard state, axis 1 once a replication
-    axis is vmapped in front); leaves with fewer dims replicate. Accepts
-    concrete arrays or ``jax.eval_shape`` abstract leaves, so it can build
-    ``shard_map`` out_specs straight from a traced output structure.
-    """
-    def spec(x):
-        nd = getattr(x, "ndim", 0)
-        if nd <= axis:
-            return P()
-        return P(*([None] * axis + [mesh_axis] + [None] * (nd - axis - 1)))
-    return jax.tree_util.tree_map(spec, tree)
-
-
-def shard_put(tree, mesh, mesh_axis: str = "shard", axis: int = 0):
-    """Device-put ``tree`` with each leaf's ``axis`` dim sharded over
-    ``mesh_axis`` — the entry layout for device-resident stream state."""
-    return jax.device_put(
-        tree, named(leading_axis_specs(tree, mesh_axis, axis), mesh))
-
-
 # ------------------------------------------------------ cache / batch ----
 
 

@@ -351,21 +351,21 @@ def _init_shard(cfg: StreamConfig, key, pop=None):
                   feat=jnp.zeros((Q + 1, cfg.learner.n_features)),
                   occ=jnp.zeros((Q,), bool),
                   count=jnp.zeros((), jnp.int32))
-        if cfg.serve:
-            bl["uid"] = jnp.full((Q + 1,), -1, jnp.int32)
     else:
+        # FIFO ring of arrival times. Serve + lm binds task identity at
+        # ARRIVAL (an injected request's label/embedding must ride the
+        # ring to its admission tick), so the ring carries it alongside the
+        # times
         bl = dict(times=jnp.zeros((Q + 1,)),
                   head=jnp.zeros((), jnp.int32),
                   count=jnp.zeros((), jnp.int32))
-        if cfg.serve:
-            bl["uid"] = jnp.full((Q + 1,), -1, jnp.int32)
         if cfg.serve and cfg.learner.feature_kind == "lm":
-            # serve + lm binds task identity at ARRIVAL (an injected
-            # request's label/embedding must ride the FIFO ring to its
-            # admission tick), so the ring carries it alongside the times
             bl["tlab"] = jnp.zeros((Q + 1,), jnp.int32)
             bl["diff"] = jnp.ones((Q + 1,))
             bl["feat"] = jnp.zeros((Q + 1, cfg.learner.n_features))
+    if cfg.serve:
+        # per-entry request uid (serve mode): -1 marks "no request here"
+        bl["uid"] = jnp.full((Q + 1,), -1, jnp.int32)
     return ws, banks, _init_window(cfg), bl
 
 
@@ -398,6 +398,37 @@ def _task_features(u1, u2, tl, diff, L: StreamLearnerConfig, C: int):
     if L.hard_sep_scale != 1.0:
         base = base * jnp.where(diff < 1.0, L.hard_sep_scale, 1.0)[..., None]
     return base + nrm
+
+
+def _arrival_identity(cfg: StreamConfig, ua, ph, hs, bank, labels_in,
+                      feat_in):
+    """Difficulty, true label and features of a tick's arrivals from the
+    uniform block ``ua``. With lm features ``ua[2]`` (the Gaussian draw's
+    first feature coordinate) picks the bank variant, so the other streams
+    stay bit-identical across kinds; injected labels (>= 0) and real-text
+    embeddings (finite ``feat_in`` rows) override the draws."""
+    L, C, F = cfg.learner, cfg.n_classes, cfg.learner.n_features
+    diff_a = jnp.where(ua[0] < ph, hs, 1.0)
+    tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
+    if L.feature_kind != "lm":
+        return diff_a, tl_a, _task_features(
+            ua[2:2 + F].T, ua[2 + F:2 + 2 * F].T, tl_a, diff_a, L, C)
+    from repro.embed.bank import bank_gather
+    if labels_in is not None:
+        tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
+    feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
+    if feat_in is not None:
+        feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None], feat_in,
+                           feat_a)
+    return diff_a, tl_a, feat_a
+
+
+def refresh_due(cfg: StreamConfig, step):
+    """Whether tick ``step`` (0-based, may be traced) runs the periodic
+    full-confusion refresh: the last of every ``refresh_every`` ticks."""
+    k = cfg.refresh_every
+    return k > 0 and step % k == k - 1
+
 
 def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
                 warmup_t, lW, lb, fuse_w, gW, gb, cap_eff=None,
@@ -448,24 +479,8 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
             dstw = jnp.where(ok, dst, Q)          # row Q is the dump row
             ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
                                 (2 + 2 * F) * M).reshape(2 + 2 * F, M)
-            diff_a = jnp.where(ua[0] < ph, hs, 1.0)
-            tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
-            if L.feature_kind == "lm":
-                # the uniform the Gaussian path would spend on the first
-                # feature coordinate picks the bank variant instead — the
-                # diff/label/vote streams stay bit-identical across kinds
-                from repro.embed.bank import bank_gather
-                if labels_in is not None:
-                    tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
-                feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
-                if feat_in is not None:
-                    # injected real-text embeddings (serve mode) override the
-                    # gathered synthetic ones; NaN rows mean "simulate"
-                    feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
-                                       feat_in, feat_a)
-            else:
-                feat_a = _task_features(ua[2:2 + F].T, ua[2 + F:2 + 2 * F].T,
-                                        tl_a, diff_a, L, C)
+            diff_a, tl_a, feat_a = _arrival_identity(cfg, ua, ph, hs, bank,
+                                                     labels_in, feat_in)
             bl_times = bl["times"].at[dstw].set(t)
             bl_diff = bl["diff"].at[dstw].set(diff_a)
             bl_tlab = bl["tlab"].at[dstw].set(tl_a)
@@ -497,52 +512,46 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
                 bl["uid"] = bl_uid
             bl_count = bl["count"]
         else:
-            # FIFO ring of arrival times (PR-2 semantics, bit-for-bit)
-            lm_ring = cfg.serve and L.feature_kind == "lm"
+            # FIFO ring: each per-entry ring ``_init_shard`` gave the backlog
+            # (times; uid in serve mode; tlab/diff/feat in serve + lm) takes
+            # the arrivals at its tail and gives up the admitted at its head
             space = Q - bl["count"]
             n_push = jnp.minimum(n_arr, space)
             dropped = (n_arr - n_push).astype(jnp.int32)
             slot = jnp.arange(M, dtype=jnp.int32)
             pos = (bl["head"] + bl["count"] + slot) % Q
             posw = jnp.where(slot < n_push, pos, Q)
-            bl_times = bl["times"].at[posw].set(t)
-            if cfg.serve:
-                bl_uid = bl["uid"].at[posw].set(uid_base + slot)
-            if lm_ring:
-                # serve + lm binds identity at ARRIVAL: draw (or accept the
-                # injected) label/embedding now and ride the ring with it
-                from repro.embed.bank import bank_gather
-                ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
-                                    3 * M).reshape(3, M)
-                diff_a = jnp.where(ua[0] < ph, hs, 1.0)
-                tl_a = jnp.floor(ua[1] * C).astype(jnp.int32).clip(0, C - 1)
-                if labels_in is not None:
-                    tl_a = jnp.where(labels_in >= 0, labels_in, tl_a)
-                feat_a = bank_gather(bank, ua[2], tl_a, diff_a)
-                if feat_in is not None:
-                    feat_a = jnp.where(jnp.isfinite(feat_in[:, 0])[:, None],
-                                       feat_in, feat_a)
-                bl_tlab = bl["tlab"].at[posw].set(tl_a)
-                bl_diff = bl["diff"].at[posw].set(diff_a)
-                bl_feat = bl["feat"].at[posw].set(feat_a)
-            bl_count = bl["count"] + n_push
-            n_adm = jnp.where(gate, jnp.minimum(bl_count, free.sum()), 0
+
+            def arriving():
+                # (ring, this tick's arrivals' values) for each ring held
+                yield "times", t
+                if "uid" in bl:
+                    yield "uid", uid_base + slot
+                if "tlab" in bl:
+                    ua = _uniform_block(seed ^ jnp.uint32(0x0BAD5EED), step,
+                                        3 * M).reshape(3, M)
+                    diff_a, tl_a, feat_a = _arrival_identity(
+                        cfg, ua, ph, hs, bank, labels_in, feat_in)
+                    yield from (("tlab", tl_a), ("diff", diff_a),
+                                ("feat", feat_a))
+
+            bl = dict(bl)
+            for k, v in arriving():
+                bl[k] = bl[k].at[posw].set(v)
+            bl["count"] = bl["count"] + n_push
+            n_adm = jnp.where(gate, jnp.minimum(bl["count"], free.sum()), 0
                               ).astype(jnp.int32)
             admit = free & (frank < n_adm)
             src = jnp.where(admit, (bl["head"] + frank) % Q, Q)
-            arr_t = bl_times[src]
-            if cfg.serve:
-                uid_w = bl_uid[src]
-            bl = dict(times=bl_times, head=(bl["head"] + n_adm) % Q,
-                      count=bl_count - n_adm)
-            if cfg.serve:
-                bl["uid"] = bl_uid
+            arr_t = bl["times"][src]
+            uid_w = bl["uid"][src] if "uid" in bl else None
+            bl["head"] = (bl["head"] + n_adm) % Q
+            bl["count"] = bl["count"] - n_adm
             bl_count = bl["count"]
-            if lm_ring:
-                bl["tlab"], bl["diff"], bl["feat"] = bl_tlab, bl_diff, bl_feat
-                diff = bl_diff[src]
-                tl = bl_tlab[src]
-                featw = bl_feat[src]
+            if "tlab" in bl:
+                # the identity rode the ring from arrival
+                diff, tl = bl["diff"][src], bl["tlab"][src]
+                featw = bl["feat"][src]
             else:
                 # fresh-task draws at ADMISSION (difficulty mixture + label)
                 uw = _uniform_block(seed ^ jnp.uint32(0x33CC33CC), step, 2 * Ws
@@ -653,7 +662,7 @@ def _shard_tick(cfg: StreamConfig, ws, banks, win, bl, n_arr, t, step, seed,
                 return lp, em["accuracy"][:P] * vpw, vpw
 
             win["logpost"], ws["est_correct"], ws["est_n"] = jax.lax.cond(
-                step % cfg.refresh_every == cfg.refresh_every - 1, _refresh,
+                refresh_due(cfg, step), _refresh,
                 lambda _: (win["logpost"], ws["est_correct"], ws["est_n"]),
                 None)
 
@@ -925,7 +934,7 @@ def _steal_plan(counts, steal_max: int, slack: int):
     return give, take
 
 
-def _steal_rebalance(cfg: StreamConfig, bl, lo, axis_name):
+def _steal_rebalance(cfg: StreamConfig, bl, mesh: _Mesh):
     """Move backlog work from hot shards to starved ones (FIFO layout).
 
     Donors pop their OLDEST entries (head side, preserving arrival times =
@@ -937,13 +946,7 @@ def _steal_rebalance(cfg: StreamConfig, bl, lo, axis_name):
     Returns (bl, received, donated) with (S_local,) per-shard counts."""
     sh = cfg.sharding
     S, Q, K = cfg.n_shards, cfg.backlog, sh.steal_max
-    Sl = bl["count"].shape[0]
-
-    def _gat(x):
-        if axis_name is None:
-            return x
-        return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-
+    Sl, lo, _gat = mesh.Sl, mesh.lo, mesh.gather
     counts = _gat(bl["count"])                              # (S,)
     give, take = _steal_plan(counts, K, sh.steal_slack)
     gcum = jnp.cumsum(give) - give                          # donation ranks
@@ -997,13 +1000,48 @@ def _steal_rebalance(cfg: StreamConfig, bl, lo, axis_name):
 
 
 # --------------------------------------------------------------------------
-# driver
+# driver: one state constructor and one step for the simulator's scan
+# (``_run_one``) and the live serve tick (``_serve_tick_impl``)
 # --------------------------------------------------------------------------
+#
+# A refactor here is held to the StableHLO text of every router program
+# (``tools/hlo_digests.py``), so code keeps the order it makes values in:
+# ``_init_state`` builds the caller's ``extra()`` between the shard and the
+# learner state; the FIFO push makes each ring's value as it reaches it.
+
+# the per-shard subtrees of the tick state, split over the "shard" mesh
+# axis by both ``shard_map`` wrappers
+_SHARDED_KEYS = ("ws", "banks", "win", "bl", "seeds")
+
+
+class _Mesh(NamedTuple):
+    """A tick's place on the ``("shard",)`` mesh: ``Sl`` local shard
+    groups from global shard ``lo``. ``gather`` all-gathers a per-shard
+    array in canonical shard order, ``gsum`` sums it over every shard;
+    both stay local when ``axis_name`` is None."""
+    Sl: int
+    lo: object
+    axis_name: Optional[str]
+
+    def gather(self, x):
+        if self.axis_name is None:
+            return x
+        return jax.lax.all_gather(x, self.axis_name, axis=0, tiled=True)
+
+    def gsum(self, x):
+        v = x.sum()
+        return v if self.axis_name is None else jax.lax.psum(v, self.axis_name)
+
+
+def _mesh(cfg: StreamConfig, axis_name=None) -> _Mesh:
+    if axis_name is None:
+        return _Mesh(cfg.n_shards, 0, None)
+    Sl = cfg.n_shards // cfg.sharding.n_devices
+    return _Mesh(Sl, jax.lax.axis_index(axis_name) * Sl, axis_name)
+
 
 def _learner_tick_params(cfg: StreamConfig, state):
-    """Per-tick learner parameters read from the replicated driver state
-    (shared by the simulator scan tick and the live serve tick so the two
-    compile the identical fusion program)."""
+    """Per-tick learner parameters read from the replicated tick state."""
     L = cfg.learner
     if L.enabled:
         lW, lb = state["learn"].W, state["learn"].b
@@ -1029,7 +1067,7 @@ def _learner_push_fit(cfg: StreamConfig, state, train, step, gat):
     learner is off). The learner is SHARED across shards: the training tree
     is all-gathered into canonical shard order first, so every device
     pushes the identical examples and fits the identical replicated model.
-    Shared by the scan tick and the serve tick."""
+    """
     from repro.learning import linear
 
     L = cfg.learner
@@ -1082,6 +1120,75 @@ def _learner_push_fit(cfg: StreamConfig, state, train, step, gat):
     return upd
 
 
+def _init_state(cfg: StreamConfig, k_init, k_seed, mesh: _Mesh, pop=None,
+                extra=dict):
+    """The tick state both drivers start from, from the caller's keys:
+    clock, step, the per-shard counter ``seeds`` and shard state (``ws``,
+    ``banks``, ``win``, ``bl``) of the local shard groups (drawn full
+    width, then sliced), the caller's ``extra()`` entries, and the
+    learner state shared across shards."""
+    from repro.learning import linear
+
+    S, L = cfg.n_shards, cfg.learner
+    # typed keys travel as key_data: extended dtypes don't support
+    # dynamic_slice
+    init_kd = jax.random.key_data(jax.random.split(k_init, S))
+    seeds = jax.random.bits(k_seed, (S,), jnp.uint32)
+    if mesh.axis_name is not None:
+        init_kd = jax.lax.dynamic_slice_in_dim(init_kd, mesh.lo, mesh.Sl)
+        seeds = jax.lax.dynamic_slice_in_dim(seeds, mesh.lo, mesh.Sl)
+    ws, banks, win, bl = jax.vmap(
+        lambda kd: _init_shard(cfg, jax.random.wrap_key_data(kd),
+                               pop))(init_kd)
+    state = dict(t=jnp.zeros(()), step=jnp.zeros((), jnp.int32),
+                 seeds=seeds, ws=ws, banks=banks, win=win, bl=bl)
+    state.update(extra())
+    if L.enabled:
+        # replay ring of finalized (features, label) pairs, +1 dump row
+        state["learn"] = linear.init(L.n_features, cfg.n_classes)
+        state["buf_X"] = jnp.zeros((L.buffer + 1, L.n_features))
+        state["buf_y"] = jnp.zeros((L.buffer + 1,), jnp.int32)
+        state["buf_n"] = jnp.zeros((), jnp.int32)
+    if cfg.routing.admission == "uncertain_learnable":
+        # the learnability head: linear over square-augmented features
+        # (routing.learnability_features), binary target "did the model's
+        # prediction agree with the crowd's final label?" stored alongside
+        # the replay ring (see the target rationale in _shard_tick)
+        state["learn2"] = linear.init(2 * L.n_features, 2)
+        state["buf_t"] = jnp.zeros((L.buffer + 1,), jnp.int32)
+    return state
+
+
+def _tick_step(cfg: StreamConfig, state, n_arr, seeds, mesh: _Mesh,
+               warmup_t, uid_base=None, feat_in=None, labels_in=None,
+               **shard_kw):
+    """One tick of the local shard groups: learner parameters, one
+    ``vmap`` of ``_shard_tick`` (``n_arr``, ``seeds`` and the serve-mode
+    injections are per-shard, None where absent), stealing, the learner's
+    push and fit, and the clock. Returns ``(new, metrics, got, gave)``."""
+    t, step = state["t"], state["step"]
+    with jax.named_scope("learner"):
+        lW, lb, fuse_w, gW, gb = _learner_tick_params(cfg, state)
+    ws, win, bl, m, train = jax.vmap(
+        lambda w, bk, wi, b, na, sd, ub, fi, li: _shard_tick(
+            cfg, w, bk, wi, b, na, t, step, sd, warmup_t, lW, lb, fuse_w,
+            gW, gb, uid_base=ub, feat_in=fi, labels_in=li, **shard_kw),
+    )(state["ws"], state["banks"], state["win"], state["bl"], n_arr, seeds,
+      uid_base, feat_in, labels_in)
+
+    with jax.named_scope("steal"):
+        if cfg.sharding.steal != "none":
+            bl, got, gave = _steal_rebalance(cfg, bl, mesh)
+        else:
+            got = gave = jnp.zeros((mesh.Sl,), jnp.int32)
+
+    new = dict(state)
+    with jax.named_scope("learner"):
+        new.update(_learner_push_fit(cfg, state, train, step, mesh.gather))
+    new.update(t=t + cfg.dt, step=step + 1, ws=ws, win=win, bl=bl)
+    return new, m, got, gave
+
+
 def _run_one(cfg: StreamConfig, horizon: int, key, warmup_t, rate_scale,
              cap_eff=None, axis_name=None, traced=None, bank=None):
     """One replication of the streaming service.
@@ -1099,8 +1206,6 @@ def _run_one(cfg: StreamConfig, horizon: int, key, warmup_t, rate_scale,
     ``traced`` is a :class:`StreamTraced` bundle of absolute overrides
     (grid path) — it subsumes ``cap_eff`` and the arrival rate and routes
     the Beta accuracy params into the worker-bank init."""
-    from repro.learning import linear
-
     rate_abs, pop, ph_t, hs_t = None, None, None, None
     if traced is not None:
         cap_eff = jnp.where(traced.votes_cap > 0,
@@ -1118,72 +1223,43 @@ def _run_one(cfg: StreamConfig, horizon: int, key, warmup_t, rate_scale,
         hs_t = jnp.where(traced.hard_scale >= 0, traced.hard_scale,
                          jnp.float32(cfg.hard_scale))
 
-    S, L, sh = cfg.n_shards, cfg.learner, cfg.sharding
-    D = sh.n_devices if axis_name is not None else 1
-    Sl = S // D                            # shard groups on this device
-    di = jax.lax.axis_index(axis_name) if axis_name is not None else 0
-    lo = di * Sl
-
-    def _gat(x):
-        if axis_name is None:
-            return x
-        return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-
-    def _gsum(x):
-        v = x.sum()
-        return jax.lax.psum(v, axis_name) if axis_name is not None else v
-
+    S = cfg.n_shards
+    mesh = _mesh(cfg, axis_name)
+    Sl, _gat, _gsum = mesh.Sl, mesh.gather, mesh.gsum
     k_init, k_seed, k_run = jax.random.split(key, 3)
-    # replicated full-width draws, sliced to the local shard group (typed
-    # keys travel as key_data: extended dtypes don't support dynamic_slice)
-    init_kd = jax.random.key_data(jax.random.split(k_init, S))
-    seeds = jax.random.bits(k_seed, (S,), jnp.uint32)
-    if axis_name is not None:
-        init_kd = jax.lax.dynamic_slice_in_dim(init_kd, lo, Sl)
-        seeds = jax.lax.dynamic_slice_in_dim(seeds, lo, Sl)
-    ws, banks, win, bl = jax.vmap(
-        lambda kd: _init_shard(cfg, jax.random.wrap_key_data(kd),
-                               pop))(init_kd)
-    zi = lambda: jnp.zeros((Sl,), jnp.int32)
-    state = dict(
-        t=jnp.zeros(()), step=jnp.zeros((), jnp.int32), key=k_run,
-        arr=init_arrival_state(cfg.arrivals),
-        ws=ws, banks=banks, win=win, bl=bl,
-        hist=jnp.zeros((Sl, cfg.tis_bins), jnp.int32),
-        done=zi(), correct=zi(),
-        sum_tis=jnp.zeros((Sl,)), votes_fin=zi(),
-        completions=zi(), done_all=zi(), dropped=zi(),
-        stolen=zi(), donated=zi(),
-        over=jnp.zeros((), jnp.int32),
-        arrived=jnp.zeros((), jnp.int32),
-        arrived_warm=jnp.zeros((), jnp.int32),
-        model_known=zi(),
-    )
     tr = cfg.trace
     tr_ph = tr is not None and tr.phases
     tr_pt = tr is not None and tr.per_tick
-    if tr_ph:
-        for pk in TRACE_PHASES:
-            state["ph_" + pk] = jnp.zeros((Sl, cfg.tis_bins), jnp.int32)
-            state["ps_" + pk] = jnp.zeros((Sl,))
-    if L.enabled:
-        # one learner per replication, shared across shards; finalized
-        # (features, label) pairs land in a replay ring (+1 dump row)
-        state["learn"] = linear.init(L.n_features, cfg.n_classes)
-        state["buf_X"] = jnp.zeros((L.buffer + 1, L.n_features))
-        state["buf_y"] = jnp.zeros((L.buffer + 1,), jnp.int32)
-        state["buf_n"] = jnp.zeros((), jnp.int32)
-    if cfg.routing.admission == "uncertain_learnable":
-        # the learnability head: linear over square-augmented features
-        # (routing.learnability_features), binary target "did the model's
-        # prediction agree with the crowd's final label?" stored alongside
-        # the replay ring (see the target rationale in _shard_tick)
-        state["learn2"] = linear.init(2 * L.n_features, 2)
-        state["buf_t"] = jnp.zeros((L.buffer + 1,), jnp.int32)
+
+    def accumulators():
+        # the run's own carry: its key and arrival process, and per-shard
+        # steady-state accumulators
+        zi = lambda: jnp.zeros((Sl,), jnp.int32)
+        acc = dict(
+            key=k_run, arr=init_arrival_state(cfg.arrivals),
+            hist=jnp.zeros((Sl, cfg.tis_bins), jnp.int32),
+            done=zi(), correct=zi(),
+            sum_tis=jnp.zeros((Sl,)), votes_fin=zi(),
+            completions=zi(), done_all=zi(), dropped=zi(),
+            stolen=zi(), donated=zi(),
+            over=jnp.zeros((), jnp.int32),
+            arrived=jnp.zeros((), jnp.int32),
+            arrived_warm=jnp.zeros((), jnp.int32),
+            model_known=zi(),
+        )
+        if tr_ph:
+            for pk in TRACE_PHASES:
+                acc["ph_" + pk] = jnp.zeros((Sl, cfg.tis_bins), jnp.int32)
+                acc["ps_" + pk] = jnp.zeros((Sl,))
+        return acc
+
+    state = _init_state(cfg, k_init, k_seed, mesh, pop, accumulators)
+    # the seeds stay out of the scan carry: every tick reads the same ones
+    seeds = state.pop("seeds")
     M, cap_total = cfg.max_arrivals_per_tick, cfg.max_arrivals_per_tick * S
 
     def tick(state, _):
-        t, step = state["t"], state["step"]
+        t = state["t"]
         key, k_arr, k_sid = jax.random.split(state["key"], 3)
         warm = t >= warmup_t
         # arrivals + shard assignment are REPLICATED draws (every device
@@ -1200,27 +1276,13 @@ def _run_one(cfg: StreamConfig, horizon: int, key, warmup_t, rate_scale,
         over = (n_arr - M).clip(0).sum() + (n_new - n_cap)
         n_arr = jnp.minimum(n_arr, M)
         if axis_name is not None:
-            n_arr = jax.lax.dynamic_slice_in_dim(n_arr, lo, Sl)
+            n_arr = jax.lax.dynamic_slice_in_dim(n_arr, mesh.lo, Sl)
 
-        lW, lb, fuse_w, gW, gb = _learner_tick_params(cfg, state)
-        ws, win, bl, m, train = jax.vmap(
-            lambda w, bk, wi, b, na, sd: _shard_tick(
-                cfg, w, bk, wi, b, na, t, step, sd, warmup_t, lW, lb,
-                fuse_w, gW, gb, cap_eff=cap_eff,
-                p_hard_t=ph_t, hard_scale_t=hs_t, bank=bank),
-        )(state["ws"], state["banks"], state["win"], state["bl"],
-          n_arr, seeds)
-
-        if sh.steal != "none":
-            bl, got, gave = _steal_rebalance(cfg, bl, lo, axis_name)
-        else:
-            got = gave = jnp.zeros((Sl,), jnp.int32)
-
-        new = dict(state)
-        new.update(_learner_push_fit(cfg, state, train, step, _gat))
+        new, m, got, gave = _tick_step(
+            cfg, state, n_arr, seeds, mesh, warmup_t, cap_eff=cap_eff,
+            p_hard_t=ph_t, hard_scale_t=hs_t, bank=bank)
         new.update(
-            t=t + cfg.dt, step=step + 1, key=key, arr=arr,
-            ws=ws, win=win, bl=bl,
+            key=key, arr=arr,
             hist=state["hist"] + m["hist"],
             done=state["done"] + m["done"],
             correct=state["correct"] + m["correct"],
@@ -1315,6 +1377,32 @@ def _bank_for(cfg: StreamConfig):
                           cfg.learner.hard_sep_scale).feats
 
 
+def _shard_specs(tree: dict, keys=_SHARDED_KEYS, axis: int = 0):
+    """``shard_map`` specs for a dict: leaves under ``keys`` split over
+    "shard" on ``axis`` (those without it replicate), the rest replicated."""
+    from jax.sharding import PartitionSpec as Pspec
+
+    return {k: jax.tree_util.tree_map(
+        lambda x: Pspec(*[None] * axis, "shard")
+        if k in keys and x.ndim > axis else Pspec(), v)
+        for k, v in tree.items()}
+
+
+def _sharded_jit(cfg: StreamConfig, body, bank, in_specs, out_specs):
+    """``body(bank, *args)`` jitted over the ``("shard",)`` mesh, first
+    argument donated. The lm bank (a per-config constant, built by the
+    caller outside any trace) is closed over, replicated, so the gaussian
+    program's signature is untouched."""
+    from repro.launch.mesh import check_stream_sharding, make_stream_mesh
+
+    D = cfg.sharding.n_devices
+    check_stream_sharding(cfg.n_shards, D)
+    fn = jax.shard_map(functools.partial(body, bank),
+                       mesh=make_stream_mesh(D), in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
+    return jax.jit(fn, donate_argnums=(0,))
+
+
 @functools.lru_cache(maxsize=None)
 def _run_sharded_jit(cfg: StreamConfig, horizon: int):
     """Compiled shard_map-partitioned runner for ``cfg.sharding.n_devices``.
@@ -1327,52 +1415,45 @@ def _run_sharded_jit(cfg: StreamConfig, horizon: int):
     diagnostics stay physically sharded over the "shard" axis."""
     from jax.sharding import PartitionSpec as Pspec
 
-    from repro.distributed.sharding import leading_axis_specs
-    from repro.launch.mesh import check_stream_sharding, make_stream_mesh
-
-    D = cfg.sharding.n_devices
-    check_stream_sharding(cfg.n_shards, D)
-    mesh = make_stream_mesh(D)
-    # the lm bank is a per-config constant: closed over (replicated on
-    # every device) rather than threaded through in_specs, so the gaussian
-    # program signature — and its compiled output — is untouched
-    bank = _bank_for(cfg)
-
-    def body(keys_data, warmup_t, rate_scale):
+    def body(bank, keys_data, warmup_t, rate_scale):
         keys = jax.random.wrap_key_data(keys_data)
         return jax.vmap(
             lambda k: _run_one(cfg, horizon, k, warmup_t, rate_scale,
                                axis_name="shard", bank=bank))(keys)
 
-    # output structure from an abstract single-device trace: everything is
-    # replicated except the per_shard subtree (sharded on axis 1, after
-    # the replication axis)
+    bank = _bank_for(cfg)       # outside any trace: it is cached
+    # output structure from an abstract single-device trace; per_shard is
+    # sharded on axis 1, after the replication axis
     shapes = jax.eval_shape(
         lambda k, w, r: jax.vmap(
             lambda kk: _run_one(cfg, horizon, kk, w, r, bank=bank))(k),
         jax.random.split(jax.random.key(0), 1), 0.0, 1.0)
-    out_specs = {
-        k: (leading_axis_specs(v, "shard", axis=1) if k == "per_shard"
-            else jax.tree_util.tree_map(lambda _: Pspec(), v))
-        for k, v in shapes.items()}
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(Pspec(), Pspec(), Pspec()),
-                       out_specs=out_specs, check_vma=False)
-    return jax.jit(fn, donate_argnums=(0,))
+    return _sharded_jit(cfg, body, bank, (Pspec(), Pspec(), Pspec()),
+                        _shard_specs(shapes, ("per_shard",), axis=1))
 
 
-def _as_stream_config(cfg) -> StreamConfig:
+def _as_stream_config(cfg, serve: bool = False) -> StreamConfig:
     """Accept a StreamConfig or a declarative ``repro.scenarios``
-    ScenarioSpec (compiled through the unified spec layer)."""
+    ScenarioSpec, compiled through the unified spec layer
+    (``to_serve_config`` with ``serve``)."""
     if isinstance(cfg, StreamConfig):
         return cfg
-    from repro.scenarios.compile import to_stream_config
-    return to_stream_config(cfg)
+    from repro.scenarios.compile import to_serve_config, to_stream_config
+    return to_serve_config(cfg) if serve else to_stream_config(cfg)
 
 
-def _validate_stream_config(cfg: StreamConfig):
-    if cfg.serve:
+_as_serve_config = functools.partial(_as_stream_config, serve=True)
+
+
+def _validate_stream_config(cfg: StreamConfig, serve: bool = False):
+    """Reject a config its driver cannot run: ``serve`` names the driver
+    (``serve_init``/``serve_tick``, else the ``run_stream*`` simulators)
+    and must match ``cfg.serve``."""
+    if cfg.serve != serve:
         raise ValueError(
+            "serve_init/serve_tick require StreamConfig.serve=True "
+            "(compile the scenario through "
+            "repro.scenarios.compile.to_serve_config)" if serve else
             "StreamConfig.serve=True is the live-injection mode: drive it "
             "one tick at a time via serve_init/serve_tick (repro.serving."
             "server), not through the run_stream* simulators")
@@ -1745,20 +1826,14 @@ def stream_summary(cfg, out) -> dict:
 # live serving: single-tick stepping with injected arrivals
 # --------------------------------------------------------------------------
 #
-# ``repro.serving.server`` drives the router ONE tick at a time: pending
-# HTTP submissions are micro-batched into per-shard injected arrival
-# counts (``StreamConfig.serve`` replaces the sampled arrival process with
-# exact counts and threads a request uid through backlog ring, window slot
-# and steal transfers), and the donated device state never round-trips to
-# host between ticks. One buffer crosses each way per tick: the injection
-# counts and uid bases go up as one ``(2, n_shards)`` int32 array, and the
-# small ``srv_*`` finalization outputs with the per-shard occupancy come
-# back packed into one int32 buffer (:class:`TickOut`), so the launch
-# allocates one output buffer and the host makes one transfer in place of
-# twelve.
-
-_SERVE_SHARDED_KEYS = ("ws", "banks", "win", "bl", "seeds")
-
+# ``repro.serving.server`` drives the router ONE tick at a time with the
+# simulator's state constructor and step. Only two things differ: arrivals
+# are injected (``StreamConfig.serve`` replaces the sampled arrival process
+# with exact per-shard counts, and the backlog and window carry request
+# uids, plus the arrival-bound task identity with lm features), and what
+# leaves the device: the donated state stays resident, the injection goes
+# up as one ``(2, n_shards)`` int32 array, and the ``srv_*`` finalization
+# outputs come back packed into one int32 buffer (:class:`TickOut`).
 
 class TickOut(collections.abc.Mapping):
     """``serve_tick``'s output bundle: a read-only mapping over ONE packed
@@ -1819,24 +1894,6 @@ def _pack_tick_out(out: dict) -> TickOut:
     return TickOut(jnp.concatenate(words), tuple(layout))
 
 
-def _as_serve_config(cfg) -> StreamConfig:
-    """Accept a serve-mode StreamConfig or a declarative ScenarioSpec
-    (lowered through ``to_serve_config``, which flips ``serve=True``)."""
-    if isinstance(cfg, StreamConfig):
-        return cfg
-    from repro.scenarios.compile import to_serve_config
-    return to_serve_config(cfg)
-
-
-def _validate_serve_config(cfg: StreamConfig):
-    _validate_stream_config(dataclasses.replace(cfg, serve=False))
-    if not cfg.serve:
-        raise ValueError(
-            "serve_init/serve_tick require StreamConfig.serve=True "
-            "(compile the scenario through "
-            "repro.scenarios.compile.to_serve_config)")
-
-
 def serve_init(cfg, seed: int = 0):
     """Build the device-resident state for :func:`serve_tick`.
 
@@ -1847,28 +1904,13 @@ def serve_init(cfg, seed: int = 0):
     every per-tick draw (task identity, vote latencies, churn), so the
     label stream for a given injection schedule is deterministic."""
     cfg = _as_serve_config(cfg)
-    _validate_serve_config(cfg)
-    from repro.learning import linear
-
-    S, L = cfg.n_shards, cfg.learner
+    _validate_stream_config(cfg, serve=True)
     k_init, k_seed = jax.random.split(jax.random.key(seed))
-    init_kd = jax.random.key_data(jax.random.split(k_init, S))
-    seeds = jax.random.bits(k_seed, (S,), jnp.uint32)
-    ws, banks, win, bl = jax.vmap(
-        lambda kd: _init_shard(cfg, jax.random.wrap_key_data(kd)))(init_kd)
-    state = dict(t=jnp.zeros(()), step=jnp.zeros((), jnp.int32),
-                 seeds=seeds, ws=ws, banks=banks, win=win, bl=bl)
-    if L.enabled:
-        state["learn"] = linear.init(L.n_features, cfg.n_classes)
-        state["buf_X"] = jnp.zeros((L.buffer + 1, L.n_features))
-        state["buf_y"] = jnp.zeros((L.buffer + 1,), jnp.int32)
-        state["buf_n"] = jnp.zeros((), jnp.int32)
-    if cfg.routing.admission == "uncertain_learnable":
-        state["learn2"] = linear.init(2 * L.n_features, 2)
-        state["buf_t"] = jnp.zeros((L.buffer + 1,), jnp.int32)
+    state = _init_state(cfg, k_init, k_seed, _mesh(cfg))
     # strip weak types (scalar-filled buffers like busy_until=inf): the
     # post-tick state is strongly typed, and an aval mismatch between the
-    # init state and tick-1's output would recompile the tick once more
+    # init state and tick-1's output would recompile the tick once more.
+    # The simulator's state never crosses a jit boundary, so it keeps them
     return jax.tree_util.tree_map(
         lambda x: jax.lax.convert_element_type(x, x.dtype), state)
 
@@ -1876,60 +1918,21 @@ def serve_init(cfg, seed: int = 0):
 def _serve_tick_impl(cfg: StreamConfig, state, n_arr, uid_base,
                      feat_in=None, labels_in=None, bank=None,
                      axis_name=None):
-    """One serve tick: mirrors ``_run_one``'s scan body with injected
+    """One serve tick: the simulator's step (``_tick_step``) with injected
     arrival counts in place of the sampled arrival process (no warmup —
     every finalization is reported). In lm mode ``feat_in``/``labels_in``
     carry per-injection real-text embeddings and known labels (NaN rows /
     -1 mean "simulate from the bank"). Returns ``(new_state, out)``."""
-    S, sh = cfg.n_shards, cfg.sharding
-    D = sh.n_devices if axis_name is not None else 1
-    Sl = S // D
-    di = jax.lax.axis_index(axis_name) if axis_name is not None else 0
-    lo = di * Sl
-
-    def _gat(x):
-        if axis_name is None:
-            return x
-        return jax.lax.all_gather(x, axis_name, axis=0, tiled=True)
-
-    t, step = state["t"], state["step"]
-    with jax.named_scope("learner"):
-        lW, lb, fuse_w, gW, gb = _learner_tick_params(cfg, state)
-    if cfg.learner.feature_kind == "lm":
-        ws, win, bl, m, train = jax.vmap(
-            lambda w, bk, wi, b, na, ub, fi, li, sd: _shard_tick(
-                cfg, w, bk, wi, b, na, t, step, sd, jnp.float32(0.0), lW,
-                lb, fuse_w, gW, gb, uid_base=ub, bank=bank, feat_in=fi,
-                labels_in=li),
-        )(state["ws"], state["banks"], state["win"], state["bl"],
-          n_arr, uid_base, feat_in, labels_in, state["seeds"])
-    else:
-        ws, win, bl, m, train = jax.vmap(
-            lambda w, bk, wi, b, na, ub, sd: _shard_tick(
-                cfg, w, bk, wi, b, na, t, step, sd, jnp.float32(0.0), lW,
-                lb, fuse_w, gW, gb, uid_base=ub),
-        )(state["ws"], state["banks"], state["win"], state["bl"],
-          n_arr, uid_base, state["seeds"])
-
-    with jax.named_scope("steal"):
-        if sh.steal != "none":
-            bl, got, gave = _steal_rebalance(cfg, bl, lo, axis_name)
-        else:
-            got = gave = jnp.zeros((Sl,), jnp.int32)
-
-    new = dict(state)
-    with jax.named_scope("learner"):
-        new.update(_learner_push_fit(cfg, state, train, step, _gat))
-    new.update(t=t + cfg.dt, step=step + 1, ws=ws, win=win, bl=bl)
-    out = dict(
-        fin=_gat(m["srv_fin"]), uid=_gat(m["srv_uid"]),
-        label=_gat(m["srv_label"]), votes=_gat(m["srv_votes"]),
-        conf=_gat(m["srv_conf"]), tis=_gat(m["srv_tis"]),
-        dropped=_gat(m["dropped"]),
-        backlog=_gat(bl["count"]),
-        in_flight=_gat(win["active"].sum(-1)),
-        stolen=_gat(got), donated=_gat(gave),
-        t=t + cfg.dt)
+    mesh = _mesh(cfg, axis_name)
+    _gat, t = mesh.gather, state["t"]
+    new, m, got, gave = _tick_step(
+        cfg, state, n_arr, state["seeds"], mesh, jnp.float32(0.0),
+        uid_base=uid_base, feat_in=feat_in, labels_in=labels_in, bank=bank)
+    out = {k: _gat(m["srv_" + k])
+           for k in ("fin", "uid", "label", "votes", "conf", "tis")}
+    out.update(dropped=_gat(m["dropped"]), backlog=_gat(new["bl"]["count"]),
+               in_flight=_gat(new["win"]["active"].sum(-1)),
+               stolen=_gat(got), donated=_gat(gave), t=t + cfg.dt)
     return new, out
 
 
@@ -1945,38 +1948,23 @@ def _serve_tick_jit(cfg: StreamConfig, state, inj, feat_in, labels_in,
 @functools.lru_cache(maxsize=None)
 def _serve_tick_sharded_jit(cfg: StreamConfig):
     """Compiled shard_map-partitioned serve tick for
-    ``cfg.sharding.n_devices`` (same mesh plumbing as ``_run_sharded_jit``:
+    ``cfg.sharding.n_devices`` (``_sharded_jit``, as ``_run_sharded_jit``:
     per-shard state subtrees live sharded over the "shard" axis, the
     packed buffer of the gathered ``srv_*`` outputs comes out replicated,
     and the state buffers are donated tick over tick)."""
     from jax.sharding import PartitionSpec as Pspec
 
-    from repro.launch.mesh import check_stream_sharding, make_stream_mesh
-
-    D = cfg.sharding.n_devices
-    check_stream_sharding(cfg.n_shards, D)
-    mesh = make_stream_mesh(D)
-    # the lm bank is a per-config constant closed over (replicated), same
-    # as _run_sharded_jit; None on the gaussian path
-    bank = _bank_for(cfg)
-
-    def body(state, inj, feat_in, labels_in):
+    def body(bank, state, inj, feat_in, labels_in):
         new, out = _serve_tick_impl(cfg, state, inj[0], inj[1],
                                     feat_in=feat_in, labels_in=labels_in,
                                     bank=bank, axis_name="shard")
         return new, _pack_tick_out(out)
 
-    state_shapes = jax.eval_shape(functools.partial(serve_init, cfg, 0))
-    state_specs = {
-        k: jax.tree_util.tree_map(
-            lambda _: Pspec("shard") if k in _SERVE_SHARDED_KEYS
-            else Pspec(), v)
-        for k, v in state_shapes.items()}
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(state_specs, Pspec(None, "shard"),
-                                 Pspec("shard"), Pspec("shard")),
-                       out_specs=(state_specs, Pspec()), check_vma=False)
-    return jax.jit(fn, donate_argnums=(0,))
+    specs = _shard_specs(jax.eval_shape(functools.partial(serve_init, cfg,
+                                                          0)))
+    return _sharded_jit(cfg, body, _bank_for(cfg),
+                        (specs, Pspec(None, "shard"), Pspec("shard"),
+                         Pspec("shard")), (specs, Pspec()))
 
 
 def serve_tick(cfg, state, n_arr, uid_base, feat=None, labels=None):

@@ -123,11 +123,11 @@ class LabelServer:
                  max_pending: int = None, request_timeout_s: float = None,
                  drain_timeout_s: float = None):
         from repro.labelstream.router import (
-            StreamConfig, _as_serve_config, _validate_serve_config,
+            StreamConfig, _as_serve_config, _validate_stream_config,
         )
 
         self.cfg = _as_serve_config(spec)
-        _validate_serve_config(self.cfg)
+        _validate_stream_config(self.cfg, serve=True)
         sv = None if isinstance(spec, StreamConfig) else spec.serve
         pick = lambda ov, dflt: ov if ov is not None else dflt
         self.host = pick(host, sv.host if sv else "127.0.0.1")
@@ -295,14 +295,13 @@ class LabelServer:
         per tick) and inject the vectors + known labels into this tick's
         arrivals."""
         import jax
-        from repro.labelstream.router import serve_tick
+        from repro.labelstream.router import refresh_due, serve_tick
 
         feat = labels = None
         if self._lm and inject:
             feat, labels = self._embed_plan(n_arr, inject)
 
-        every = self.cfg.refresh_every
-        refresh = every > 0 and self.ticks % every == every - 1
+        refresh = refresh_due(self.cfg, self.ticks)
         with timing.span("serve.refresh_tick") if refresh \
                 else contextlib.nullcontext(), timing.span("serve.tick"):
             self.state, out = serve_tick(self.cfg, self.state, n_arr,

@@ -75,6 +75,31 @@ def _serve_packed_parity(scenario: str, D: int) -> dict:
                 finalized=sum(int(o["fin"].sum()) for o in oD))
 
 
+def _lm_sharded_from_cold_caches(D: int):
+    """The sharded ``lm_stream`` run on ``D`` devices with the embedding
+    bank's and the encoder's caches empty, so the runner builds the bank
+    itself: ``(out, error)``, ``error`` None when the run completed."""
+    from repro import scenarios
+    from repro.embed import bank, encoder
+    from repro.labelstream import router
+    from repro.scenarios.compile import to_stream_config
+
+    def clear():
+        for f in (bank.embedding_bank, bank._bank_stats,
+                  encoder.model_params, encoder.projection,
+                  router._run_sharded_jit):
+            f.cache_clear()
+
+    clear()
+    cfg = to_stream_config(scenarios.get_scenario(
+        "lm_stream", {"sharding.n_devices": D}))
+    try:
+        return router.run_stream(cfg, HORIZON, n_reps=N_REPS, seed=3), None
+    except Exception as e:  # noqa: BLE001 -- the error is the report
+        clear()       # a cache left holding tracers would fail later checks
+        return None, f"{type(e).__name__}: {e}"[:400]
+
+
 def collect() -> dict:
     import jax
     import jax.numpy as jnp
@@ -211,6 +236,10 @@ def collect() -> dict:
     # (a) the raw gather: pmapped device-parallel lookups must equal the
     # single-device vmap over the same indices (the bank is replicated —
     # a sharded gather that drifted would silently corrupt LM features)
+    # (0) first, from cold caches: the sharded runner builds the bank
+    lD_cold, cold_err = _lm_sharded_from_cold_caches(min(2, D))
+    report["lm_sharded_cold_caches"] = cold_err or True
+
     from repro.embed.bank import bank_gather, embedding_bank
     from repro.scenarios.compile import to_embed_config
     lm_spec = scenarios.get_scenario("lm_stream")
@@ -241,6 +270,8 @@ def collect() -> dict:
     lD = run_stream(to_stream_config(lmD), HORIZON, n_reps=N_REPS, seed=3)
     a, b = _common(l1, lD)
     report["lm_parity_sharded"] = _tree_equal(a, b)
+    report["lm_sharded_cold_parity"] = (lD_cold is not None
+                                        and _tree_equal(lD_cold, lD))
 
     # ---- sharded serve tick: one packed buffer out, bitwise the single-
     # device tick, on the Gaussian and the LM path (2 devices) ----------

@@ -209,7 +209,7 @@ def test_sharded_serve_tick_compiles_on_four_chips(four_chip_mesh):
     state = jax.eval_shape(functools.partial(router.serve_init, cfg, 0))
     shard = NamedSharding(four_chip_mesh, PartitionSpec("shard"))
     rep = NamedSharding(four_chip_mesh, PartitionSpec())
-    state = {k: _sds(v, shard if k in router._SERVE_SHARDED_KEYS else rep)
+    state = {k: _sds(v, shard if k in router._SHARDED_KEYS else rep)
              for k, v in state.items()}
     inj = jax.ShapeDtypeStruct(
         (2, cfg.n_shards), jnp.int32,

@@ -479,6 +479,30 @@ def test_packed_tick_out_matches_unpacked_fields(name):
     assert fin > 0
 
 
+@pytest.mark.parametrize("name", ["serve_default", "lm_stream"])
+def test_serve_tick_compiles_once(name):
+    """The state ``serve_init`` builds has the avals of every later tick's
+    state (weak types stripped), so once the first tick has compiled the
+    jitted tick, the next ticks add no compiled entry."""
+    import jax
+    from repro import scenarios
+    from repro.labelstream.router import (
+        _serve_tick_jit, serve_init, serve_tick,
+    )
+
+    cfg = scenarios.to_serve_config(scenarios.get_scenario(name))
+    S = cfg.n_shards
+    n, base = np.ones((S,), np.int32), np.zeros((S,), np.int32)
+    state, out = serve_tick(cfg, serve_init(cfg, seed=0), n, base)
+    jax.device_get(out)
+    size = _serve_tick_jit._cache_size()
+    for _ in range(2):
+        base = base + n
+        state, out = serve_tick(cfg, state, n, base)
+        jax.device_get(out)
+        assert _serve_tick_jit._cache_size() == size
+
+
 def test_packed_tick_out_keeps_nonfinite_floats_bit_exact():
     """NaN (with its payload), infinities and -0.0 survive the bit-cast
     packing, next to bool and int32 fields and a scalar."""

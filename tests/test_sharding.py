@@ -114,6 +114,14 @@ def test_embedding_bank_sharded_gather_parity(report):
     assert report["lm_parity_sharded"] is True
 
 
+def test_sharded_lm_stream_from_cold_caches(report):
+    """A process's first sharded lm_stream run, with the embedding bank
+    and the encoder's params not yet built, builds them outside its
+    traces and matches the run that found them built."""
+    assert report["lm_sharded_cold_caches"] is True
+    assert report["lm_sharded_cold_parity"] is True
+
+
 @pytest.mark.parametrize("name", ["stream_sharded", "lm_stream"])
 def test_sharded_serve_tick_packed_output(report, name):
     """The sharded serve tick returns one packed, replicated buffer that
